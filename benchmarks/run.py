@@ -166,6 +166,11 @@ def main() -> None:
                          "emits schema-valid JSON")
     args = ap.parse_args()
     quick = args.quick or args.smoke
+    if str(ROOT / "src") not in sys.path:
+        sys.path.insert(0, str(ROOT / "src"))
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.smoke:
         failures = preflight()

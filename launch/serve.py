@@ -32,6 +32,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import OP_ADD_E, OP_ADD_V, OP_REM_E  # noqa: E402
 from repro.runtime.serve_loop import GraphCoServer  # noqa: E402
 
@@ -85,6 +86,7 @@ def main(argv=None) -> int:
     ap.add_argument("--report", default=None,
                     help="JSONL report path (default: <wal-dir>/report.jsonl)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     os.makedirs(args.wal_dir, exist_ok=True)
     report_path = args.report or os.path.join(args.wal_dir, "report.jsonl")

@@ -13,7 +13,7 @@ O(E) random accesses — the hardware-adaptation core of this reproduction
 
   "jnp"           float32-MXU reference: unpack the packed words, expand via
                   a frontier mat-vec (always available)
-  "pallas"        kernels/bfs_step on the unpacked view (interpret on CPU)
+  "pallas"        kernels/bfs_step on the unpacked view
   "packed"        pure-jnp AND/OR reduction over the packed uint32 words —
                   no unpack, no matmul, ~32x less adjacency traffic
   "packed_pallas" kernels/bfs_step packed kernel (words streamed HBM->VMEM)
@@ -81,8 +81,10 @@ def default_backend() -> str:
 
     "hybrid" since the direction-optimizing engine landed (previously
     "packed"); override with the ``REPRO_BFS_BACKEND`` environment variable
-    (e.g. force "packed_pallas" on a real TPU to keep the superstep in the
-    Pallas kernels). tests/test_hybrid.py pins the resolution.
+    (e.g. "hybrid_pallas" runs both directions through the Pallas kernels,
+    compiled on a TPU and interpreted elsewhere — kernels/mosaic.py). No
+    backend has been measured against another on a chip yet.
+    tests/test_hybrid.py pins the resolution.
     """
     return os.environ.get("REPRO_BFS_BACKEND", "hybrid")
 

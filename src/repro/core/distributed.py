@@ -29,8 +29,7 @@ partitioned; owner-routed mutation). The production scale-out path is
 ``core.partition`` (DESIGN.md §8): adjacency rows sharded, version metadata
 replicated, engines bit-identical to the dense ones. partition.py shares
 this module's mesh axis (``AXIS``), row-block arithmetic
-(``_row_block_info``) and jax-version shims (``shard_map`` import,
-``_SM_NOCHECK``, ``_pvary``).
+(``_row_block_info``) and the ``_pvary`` helper.
 """
 from __future__ import annotations
 
@@ -40,21 +39,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x keeps it in jax.experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# "skip the replication/varying-manual-axes check" kwarg, renamed across jax
-# versions (0.4.x: check_rep, >= 0.6: check_vma)
-import inspect as _inspect
-
-_HAS_VMA = "check_vma" in _inspect.signature(shard_map).parameters
-_SM_NOCHECK = {"check_vma": False} if _HAS_VMA else {"check_rep": False}
-# 0.4.x's check_rep cannot infer replication through fori_loop/switch at all;
-# >= 0.6's VMA checker can and should stay ON where it passes (dapply_ops)
-_SM_NOCHECK_LEGACY_ONLY = {} if _HAS_VMA else {"check_rep": False}
 
 from repro.core.graph import (
     EMPTY_KEY,
@@ -104,25 +90,18 @@ def _global_find(vkey_l, valive_l, keys, row0):
 
 
 def _pvary(x):
-    """Mark a shard-replicated value as device-varying (no-op if it already is).
-
-    jax < 0.6 has neither ``jax.typeof`` nor ``jax.lax.pvary`` (and no varying
-    manual-axes check that would need them) — identity there.
-    """
-    pvary = getattr(jax.lax, "pvary", None)
-    typeof = getattr(jax, "typeof", None)
-    if pvary is None or typeof is None:
+    """Mark a shard-replicated value as device-varying (no-op if it already
+    is), so a loop carry that starts replicated can hold per-shard values."""
+    if AXIS in jax.typeof(x).vma:
         return x
-    vma = getattr(typeof(x), "vma", frozenset())
-    return x if AXIS in vma else pvary(x, (AXIS,))
+    return jax.lax.pcast(x, AXIS, to="varying")
 
 
 def _row_block_info(nrows_total, size):
     """(shard id, axis size, rows per shard, first owned row).
 
     ``size`` is the STATIC mesh-axis extent (callers pass mesh.shape[AXIS]):
-    rows-per-shard feeds dynamic_slice sizes, which must be static, and
-    jax 0.4.x has no ``jax.lax.axis_size`` to query it inside shard_map.
+    rows-per-shard feeds dynamic_slice sizes, which must be static.
     """
     s = jax.lax.axis_index(AXIS)
     per = nrows_total // size
@@ -149,7 +128,7 @@ def dbfs(mesh: Mesh, state: GraphState, src_slot, dst_slot):
         out_specs=(P(), P(), P(), P(), P()),
         # Outputs are value-replicated (every shard computes the full combined
         # frontier/parents), which the VMA analysis cannot infer past pvary.
-        **_SM_NOCHECK,
+        check_vma=False,
     )
     def run(vkey_l, valive_l, adjw_l, src, dst):
         _, _, per, row0 = _row_block_info(v, mesh.shape[AXIS])
@@ -225,10 +204,6 @@ def dapply_ops(mesh: Mesh, state: GraphState, ops: OpBatch):
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS, None),
                   P(), P(), P(), P()),
         out_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS, None), P()),
-        # jax 0.4.x's replication checker cannot infer through the
-        # fori_loop/switch lattice here (newer jax's VMA checker can, and
-        # stays enabled); the outputs are correct by the psum/pmax combines.
-        **_SM_NOCHECK_LEGACY_ONLY,
     )
     def run(vkey_l, valive_l, vver_l, ecnt_l, adjw_l, opc, k1, k2, expect):
         sid, ssize, per, row0 = _row_block_info(v, mesh.shape[AXIS])

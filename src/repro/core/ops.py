@@ -91,17 +91,16 @@ from repro.core.graph import (
 # Packed-word adjacency primitives (DESIGN.md §10): every edge mutation is a
 # masked bit set/clear on one uint32 word instead of a dense row/cell write.
 # ----------------------------------------------------------------------------
-def _clear_row_col(adj_packed, slot, do):
+def _clear_row_col(adj_packed, slot):
     """Clear adjacency row ``slot`` and column bit ``slot`` in every row
-    (the stale-adjacency scrub a slot reuse needs), when ``do``.
+    (the stale-adjacency scrub a slot reuse needs).
 
     The scrubbed bit set {(slot, *)} ∪ {(*, slot)} is its own transpose, so
     the SAME helper scrubs the in-adjacency (DESIGN.md §11) — every caller
     applies it to both packed matrices."""
     w, m = bit_word(slot), bit_mask(slot)
     cleared = adj_packed.at[slot, :].set(jnp.uint32(0))
-    cleared = cleared.at[:, w].set(cleared[:, w] & ~m)
-    return jnp.where(do, cleared, adj_packed)
+    return cleared.at[:, w].set(cleared[:, w] & ~m)
 
 
 def _set_edge_bit(adj_packed, row, col, present, do):
@@ -122,115 +121,89 @@ def _free_slot(state: GraphState) -> jax.Array:
     return jnp.where(jnp.any(free), idx.astype(jnp.int32), jnp.int32(-1))
 
 
-def _add_vertex(state: GraphState, k: jax.Array):
-    slot = find_slot(state, k)
-    exists = slot >= 0
+def _apply_one(state: GraphState, opcode, k1, k2, expect, live=True):
+    """Apply a single op; returns (state', result).
+
+    Branch-free: every op kind's decision is computed from ``state`` and its
+    writes are masked by the opcode (and by ``live``), so the serial lane
+    loop rewrites a few words of the packed matrices in place. Keep it so:
+    XLA copies both packed matrices out of every branch of a
+    ``lax.switch``/``lax.cond`` that returns the state — O(V^2/32) bytes
+    per serial op. The one heavy write, the AddVertex scrub, runs as a
+    0/1-trip loop for the same reason.
+
+    AddVertex takes the first truly-free slot and scrubs the slot's stale
+    adjacency (a reused slot may carry a dead predecessor's edges);
+    RemoveVertex marks the vertex and bumps the ``ecnt`` of every live
+    in-edge source, read off ONE maintained in-adjacency row (DESIGN.md
+    §11) — the paper's adversary argument needs those rows' versions to
+    move; edge ops are the masked single-bit RMW on both mirrors plus the
+    paper's FAA on the source row's ``ecnt``.
+    """
+    opcode = jnp.where(live, opcode, OP_NOP)
+    s1 = find_slot(state, k1)
+    s2 = find_slot(state, k2)
+    is_addv = opcode == OP_ADD_V
+    is_remv = opcode == OP_REM_V
+    is_adde = opcode == OP_ADD_E
+    is_reme = opcode == OP_REM_E
+    v = state.capacity
+
     new = _free_slot(state)
-    full = (~exists) & (new < 0)
-    do = (~exists) & (new >= 0)
-    tgt = jnp.maximum(new, 0)
-    vkey = state.vkey.at[tgt].set(jnp.where(do, k, state.vkey[tgt]))
-    valive = state.valive.at[tgt].set(jnp.where(do, True, state.valive[tgt]))
-    vver = state.vver.at[tgt].add(jnp.where(do, 1, 0))
-    # A reused slot may carry stale adjacency from a dead predecessor: clear
-    # (the scrub set is transpose-symmetric, so the in-adjacency takes the
-    # identical clear — DESIGN.md §11).
-    adj = _clear_row_col(state.adj_packed, tgt, do)
-    adj_in = _clear_row_col(state.adj_in_packed, tgt, do)
-    ecnt = state.ecnt.at[tgt].set(jnp.where(do, 0, state.ecnt[tgt]))
-    res = jnp.where(exists, R_FALSE, jnp.where(full, R_TABLE_FULL, R_TRUE))
-    return GraphState(vkey, valive, vver, ecnt, adj, adj_in), res.astype(jnp.int32)
-
-
-def _remove_vertex(state: GraphState, k: jax.Array):
-    slot = find_slot(state, k)
-    do = slot >= 0
-    tgt = jnp.maximum(slot, 0)
-    # Logical removal (paper line 21): mark the vertex; leave edges lazily.
-    valive = state.valive.at[tgt].set(jnp.where(do, False, state.valive[tgt]))
-    vver = state.vver.at[tgt].add(jnp.where(do, 1, 0))
-    ecnt = state.ecnt.at[tgt].add(jnp.where(do, 1, 0))
-    # Incoming edges must invalidate their sources' collects: removing v
-    # changes reachability through every u with (u -> v), and the paper's
-    # adversary argument needs those rows' versions to move. Bump ecnt of all
-    # sources of live in-edges — ONE maintained in-adjacency row instead of
-    # a strided column gather (DESIGN.md §11).
-    in_src = unpack_bits(state.adj_in_packed[tgt], state.capacity) \
-        & state.valive & do
-    ecnt = ecnt + in_src.astype(jnp.int32)
-    res = jnp.where(do, R_TRUE, R_FALSE)
-    return GraphState(state.vkey, valive, vver, ecnt, state.adj_packed,
-                      state.adj_in_packed), res.astype(jnp.int32)
-
-
-def _edge_op(state: GraphState, k, l, expect, *, add: bool):
-    sk = find_slot(state, k)
-    sl = find_slot(state, l)
-    both = (sk >= 0) & (sl >= 0)
-    rk, rl = jnp.maximum(sk, 0), jnp.maximum(sl, 0)
+    exists = s1 >= 0
+    do_av = is_addv & ~exists & (new >= 0)
+    r_addv = jnp.where(exists, R_FALSE,
+                       jnp.where(new < 0, R_TABLE_FULL, R_TRUE))
+    do_rv = is_remv & exists
+    r_remv = jnp.where(exists, R_TRUE, R_FALSE)
+    both = (s1 >= 0) & (s2 >= 0)
+    rk, rl = jnp.maximum(s1, 0), jnp.maximum(s2, 0)
     cas_ok = (expect < 0) | (state.ecnt[rk] == expect)
     present = get_bit(state.adj_packed, rk, rl)
-    if add:
-        do = both & cas_ok & ~present
-        ok_res = jnp.where(present, R_EDGE_PRESENT, R_EDGE_ADDED)
-    else:
-        do = both & cas_ok & present
-        ok_res = jnp.where(present, R_EDGE_REMOVED, R_EDGE_NOT_PRESENT)
-    adj = _set_edge_bit(state.adj_packed, rk, rl, jnp.asarray(add), do)
-    # mirrored single-bit RMW on the in-adjacency (DESIGN.md §11)
-    adj_in = _set_edge_bit(state.adj_in_packed, rl, rk, jnp.asarray(add), do)
-    ecnt = state.ecnt.at[rk].add(jnp.where(do, 1, 0))  # the paper's FAA
-    res = jnp.where(
-        both,
-        jnp.where(cas_ok, ok_res, R_CAS_FAIL),
-        R_VERTEX_NOT_PRESENT,
-    )
-    return GraphState(state.vkey, state.valive, state.vver, ecnt, adj,
-                      adj_in), res.astype(jnp.int32)
+    do_add = is_adde & both & cas_ok & ~present
+    do_rem = is_reme & both & cas_ok & present
+    r_adde = jnp.where(both, jnp.where(cas_ok, jnp.where(
+        present, R_EDGE_PRESENT, R_EDGE_ADDED), R_CAS_FAIL),
+        R_VERTEX_NOT_PRESENT)
+    r_reme = jnp.where(both, jnp.where(cas_ok, jnp.where(
+        present, R_EDGE_REMOVED, R_EDGE_NOT_PRESENT), R_CAS_FAIL),
+        R_VERTEX_NOT_PRESENT)
+    r_cone = jnp.where(both, jnp.where(present, R_EDGE_PRESENT,
+                                       R_EDGE_NOT_PRESENT),
+                       R_VERTEX_NOT_PRESENT)
+    r_conv = jnp.where(exists, R_TRUE, R_FALSE)
 
+    # metadata writes ("drop" parks a masked-off write past the table)
+    at = jnp.where(do_av, new, v)
+    rt = jnp.where(do_rv, s1, v)
+    et = jnp.where(do_add | do_rem, rk, v)
+    vkey = state.vkey.at[at].set(k1, mode="drop")
+    valive = state.valive.at[at].set(True, mode="drop")
+    valive = valive.at[rt].set(False, mode="drop")
+    vver = state.vver.at[at].add(1, mode="drop").at[rt].add(1, mode="drop")
+    in_src = unpack_bits(state.adj_in_packed[jnp.maximum(s1, 0)], v) \
+        & state.valive & do_rv
+    ecnt = (state.ecnt.at[at].set(0, mode="drop").at[rt].add(1, mode="drop")
+            .at[et].add(1, mode="drop") + in_src.astype(jnp.int32))
 
-def _contains_edge_op(state: GraphState, k, l):
-    sk = find_slot(state, k)
-    sl = find_slot(state, l)
-    both = (sk >= 0) & (sl >= 0)
-    present = get_bit(state.adj_packed, jnp.maximum(sk, 0), jnp.maximum(sl, 0))
-    res = jnp.where(
-        both,
-        jnp.where(present, R_EDGE_PRESENT, R_EDGE_NOT_PRESENT),
-        R_VERTEX_NOT_PRESENT,
-    )
-    return state, res.astype(jnp.int32)
+    # adjacency: the mirrored single-bit RMW of an edge op (DESIGN.md §11),
+    # then the reused-slot scrub of an AddVertex, run as a 0/1-trip loop so
+    # the column pass costs nothing on the lanes that do not allocate
+    adj = _set_edge_bit(state.adj_packed, rk, rl, do_add, do_add | do_rem)
+    adj_in = _set_edge_bit(state.adj_in_packed, rl, rk, do_add,
+                           do_add | do_rem)
+    tgt = jnp.maximum(new, 0)
+    adj, adj_in = jax.lax.fori_loop(
+        0, do_av.astype(jnp.int32),
+        lambda _, m: (_clear_row_col(m[0], tgt), _clear_row_col(m[1], tgt)),
+        (adj, adj_in))
 
-
-def _apply_one(state: GraphState, opcode, k1, k2, expect):
-    """Apply a single op; returns (state', result). Branch-free lax.switch."""
-
-    def do_nop(s):
-        return s, jnp.int32(R_FALSE)
-
-    def do_addv(s):
-        return _add_vertex(s, k1)
-
-    def do_remv(s):
-        return _remove_vertex(s, k1)
-
-    def do_conv(s):
-        return s, jnp.where(find_slot(s, k1) >= 0, R_TRUE, R_FALSE).astype(jnp.int32)
-
-    def do_adde(s):
-        return _edge_op(s, k1, k2, expect, add=True)
-
-    def do_reme(s):
-        return _edge_op(s, k1, k2, expect, add=False)
-
-    def do_cone(s):
-        return _contains_edge_op(s, k1, k2)
-
-    return jax.lax.switch(
-        jnp.clip(opcode, 0, 6),
-        [do_nop, do_addv, do_remv, do_conv, do_adde, do_reme, do_cone],
-        state,
-    )
+    res = jnp.select(
+        [opcode == OP_ADD_V, is_remv, opcode == OP_CON_V, is_adde, is_reme,
+         opcode == OP_CON_E],
+        [r_addv, r_remv, r_conv, r_adde, r_reme, r_cone], R_FALSE)
+    return (GraphState(vkey, valive, vver, ecnt, adj, adj_in),
+            res.astype(jnp.int32))
 
 
 # ----------------------------------------------------------------------------
@@ -247,12 +220,9 @@ def _serial_masked(state: GraphState, ops: OpBatch, mask: jax.Array,
 
     def body(i, carry):
         st, res = carry
-
-        def run(st):
-            st2, r = _apply_one(st, ops.opcode[i], ops.key1[i], ops.key2[i], ops.expect[i])
-            return st2, res.at[i].set(r)
-
-        return jax.lax.cond(mask[i], run, lambda st: (st, res), st)
+        st, r = _apply_one(st, ops.opcode[i], ops.key1[i], ops.key2[i],
+                           ops.expect[i], live=mask[i])
+        return st, res.at[i].set(jnp.where(mask[i], r, res[i]))
 
     return jax.lax.fori_loop(0, ops.lanes, body, (state, res0))
 
@@ -598,21 +568,26 @@ def compact(state: GraphState) -> GraphState:
 # ----------------------------------------------------------------------------
 # Convenience single-op API (host-facing, used by examples/benchmarks)
 # ----------------------------------------------------------------------------
+def _single(state: GraphState, opcode: int, k, l=-1):
+    return _apply_one(state, jnp.int32(opcode), jnp.asarray(k, jnp.int32),
+                      jnp.asarray(l, jnp.int32), jnp.int32(-1))
+
+
 @jax.jit
 def add_vertex(state: GraphState, k):
-    return _add_vertex(state, jnp.asarray(k, jnp.int32))
+    return _single(state, OP_ADD_V, k)
 
 
 @jax.jit
 def remove_vertex(state: GraphState, k):
-    return _remove_vertex(state, jnp.asarray(k, jnp.int32))
+    return _single(state, OP_REM_V, k)
 
 
 @jax.jit
 def add_edge(state: GraphState, k, l):
-    return _edge_op(state, jnp.asarray(k, jnp.int32), jnp.asarray(l, jnp.int32), jnp.int32(-1), add=True)
+    return _single(state, OP_ADD_E, k, l)
 
 
 @jax.jit
 def remove_edge(state: GraphState, k, l):
-    return _edge_op(state, jnp.asarray(k, jnp.int32), jnp.asarray(l, jnp.int32), jnp.int32(-1), add=False)
+    return _single(state, OP_REM_E, k, l)

@@ -17,8 +17,8 @@ core/distributed.py):
     compute with zero communication.
 
 The placement rules live in ``parallel.sharding.graph_state_specs``; the
-inside-shard_map helpers (row-block arithmetic, jax-version shims) are
-shared with ``core.distributed``.
+inside-shard_map helpers (row-block arithmetic, ``_pvary``) are shared
+with ``core.distributed``.
 
 Engines (each bit-identical to its dense counterpart — the property suite
 tests/test_linearizability_prop.py enforces it):
@@ -58,6 +58,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import graph as ggraph
@@ -74,11 +75,9 @@ from repro.core.bfs import (
 )
 from repro.core.distributed import (
     AXIS,
-    _SM_NOCHECK,
     _pvary,
     _row_block_info,
     make_graph_mesh,
-    shard_map,
 )
 from repro.core.graph import (
     EMPTY_KEY,
@@ -222,7 +221,7 @@ def compact(state: ShardedGraphState) -> ShardedGraphState:
 
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(P(AXIS, None), P()),
-        out_specs=P(AXIS, None), **_SM_NOCHECK,
+        out_specs=P(AXIS, None), check_vma=False,
     )
     def scrub(adjw_l, keep_g):
         _, _, per, row0 = _row_block_info(v, size)
@@ -278,10 +277,9 @@ def apply_ops_fast(state: ShardedGraphState, ops: OpBatch):
         in_specs=(P(), P(), P(), P(), P(AXIS, None), P(AXIS, None),
                   P(), P(), P(), P(), P(), P(), P(), P()),
         out_specs=(P(), P(), P(), P(), P(AXIS, None), P(AXIS, None), P()),
-        # Metadata outputs are value-replicated (every shard computes the
-        # same result from replicated inputs + deterministic collectives),
-        # which 0.4.x's check_rep cannot infer through fori_loop.
-        **_SM_NOCHECK,
+        # Metadata outputs are value-replicated: every shard computes the
+        # same result from replicated inputs + deterministic collectives.
+        check_vma=False,
     )
     def run(vkey, valive, vver, ecnt, adj_l, adjin_l,
             opc, k1, k2, expect, cleanv, serialv, wantsv, slotv):
@@ -400,15 +398,16 @@ def apply_ops_fast(state: ShardedGraphState, ops: OpBatch):
             ltgt = jnp.where((ltgt >= 0) & (ltgt < per), ltgt, per)
             adj_l = adj_l.at[ltgt, :].set(jnp.uint32(0), mode="drop")
             adjin_l = adjin_l.at[ltgt, :].set(jnp.uint32(0), mode="drop")
-            # column-bit scrub, guarded by the scalar do_av (transpose-
-            # symmetric, so the in-rows take the identical mask, §11)
-            tsafe = jnp.minimum(tgt, v - 1)
-            colw = adj_l[:, bit_word(tsafe)]
-            adj_l = adj_l.at[:, bit_word(tsafe)].set(
-                jnp.where(do_av, colw & ~bit_mask(tsafe), colw))
-            colw_in = adjin_l[:, bit_word(tsafe)]
-            adjin_l = adjin_l.at[:, bit_word(tsafe)].set(
-                jnp.where(do_av, colw_in & ~bit_mask(tsafe), colw_in))
+            # column-bit scrub (transpose-symmetric, so the in-rows take the
+            # identical mask, §11). A strided pass over every local row: it
+            # runs as a 0/1-trip loop, so only an allocating lane pays it
+            # (do_av is replicated, so every shard takes the same trip count)
+            tw, tm = bit_word(tgt), bit_mask(tgt)
+            adj_l, adjin_l = jax.lax.fori_loop(
+                0, do_av.astype(jnp.int32),
+                lambda _, mats: tuple(x.at[:, tw].set(x[:, tw] & ~tm)
+                                      for x in mats),
+                (adj_l, adjin_l))
             r_addv = jnp.where(exists, R_FALSE, jnp.where(have, R_TRUE, R_TABLE_FULL))
 
             # RemoveVertex (in-edge-source bumps read the pre-lane liveness)
@@ -420,10 +419,17 @@ def apply_ops_fast(state: ShardedGraphState, ops: OpBatch):
             ecnt = ecnt.at[t].add(1, mode="drop")
             col = jnp.maximum(sa, 0)
             valive_l = jax.lax.dynamic_slice(valive_in, (row0,), (per,))
-            bump_l = do_rv & ((adj_l[:, bit_word(col)] & bit_mask(col)) > 0) \
-                & valive_l
-            bump = jax.lax.all_gather(bump_l, AXIS, tiled=True)
-            ecnt = ecnt + bump.astype(jnp.int32)
+
+            def bump_in_sources(_, e):
+                # a strided column pass + all_gather: a 0/1-trip loop, so
+                # only a removing lane pays it (do_rv is replicated)
+                bump_l = ((adj_l[:, bit_word(col)] & bit_mask(col)) > 0) \
+                    & valive_l
+                bump = jax.lax.all_gather(bump_l, AXIS, tiled=True)
+                return e + bump.astype(jnp.int32)
+
+            ecnt = jax.lax.fori_loop(0, do_rv.astype(jnp.int32),
+                                     bump_in_sources, ecnt)
             r_remv = jnp.where(sa >= 0, R_TRUE, R_FALSE)
 
             # ContainsVertex
@@ -566,9 +572,9 @@ def _multi_bfs_jit(state: ShardedGraphState, src_slots, dst_slots,
         mesh=mesh,
         in_specs=(P(), P(AXIS, None), P(AXIS, None), P(), P()),
         out_specs=(P(), P(), P(), P(), P(), P()),
-        # Outputs are value-replicated (combined via psum/pmin every
-        # superstep), which the 0.4.x checker cannot infer past while_loop.
-        **_SM_NOCHECK,
+        # Outputs are value-replicated: combined via psum/pmin every
+        # superstep.
+        check_vma=False,
     )
     def run(alive, adjw_l, adjw_in_l, srcs, dsts):
         _, _, per, row0 = _row_block_info(v, size)

@@ -20,23 +20,31 @@ have finished (early-exit masking zeroes their frontiers, core/bfs.py) and
 survivors touch few rows, cost almost nothing.
 
 Parent extraction (smallest source row per (query, dst) pair) is a masked
-min that needs a [TQ, TR, TC] candidate volume. VMEM budget decides the
-strategy statically: the broadcast fits for small slabs
-(8*256*256*4 = 2 MiB << 16 MiB VMEM); larger slabs fall back to a fori_loop
-over query rows holding only one [TR, TC] slice (256 KiB) at a time.
+min over the tile's rows, one query at a time (a ``fori_loop`` over the
+slab), so only one [TR, TC] candidate slice is live in VMEM. The query's
+frontier flags are needed as a [TR, 1] sublane column: the wrapper also
+passes the slab transposed ([R, TQ]) and the kernel reads column q off it
+with a masked lane reduction — no in-kernel relayout.
+
+Mosaic layout rules shape every operand: all blocks are 2-D, the last
+block dim is a multiple of 128 lanes or the whole axis, and vectors
+(alive) travel as [1, V] rows. The dense adjacency arrives as uint8 and is
+widened through int32 (Mosaic has no direct uint8 -> f32 cast).
 
 VMEM footprint per program instance (TQ=64, TR=TC=256 defaults):
-    adj tile       256*256 u8->f32  = 256 KiB
-    frontier slab  64*256 f32       =  64 KiB
-    out slabs      2 * 64*256 i32   = 128 KiB
-    parent scratch (see above)      <= 4 MiB        << 16 MiB VMEM
+    adj tile       256*256 u8       =  64 KiB (+ f32 copy 256 KiB)
+    frontier slabs 2 * 64*256 f32   = 128 KiB
+    out slabs      2 * 64*256 i32   = 128 KiB        << 16 MiB VMEM
 
 The PACKED variant (``multi_bfs_step_packed_pallas``, DESIGN.md §10)
 streams uint32[TR, TW] word tiles of the packed adjacency — 32x less HBM
-per superstep, the term this kernel is bandwidth-bound on — and expands
-every query's frontier with a bitwise OR fold over its active rows' words
-instead of the MXU matmul. Parent extraction unpacks the word tile in
-registers; the HBM stream stays packed.
+per superstep, the term this kernel is bandwidth-bound on. Column
+c = 32*w + b of a word tile is bit b of word w, so the kernel works in a
+BIT-MAJOR column order: for each bit b it masks ``(words >> b) & 1``
+([TR, TW], lane-aligned — no unpack reshape), takes the per-query masked
+row min (the parent) and ORs ``parent < MAX`` back into the reach word.
+Parents come out as [TQ*32, W] rows (row q*32+b, lane w) and reach/new as
+packed [TQ, W] words; the wrapper restores the [Q, W*32] column order.
 """
 from __future__ import annotations
 
@@ -45,19 +53,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.graph import WORD_BITS, or_reduce, unpack_bits
+from repro.core.graph import WORD_BITS, pack_bits, unpack_bits
+from repro.kernels.mosaic import interpret_mode
 
 INT32_MAX = 2**31 - 1  # python int: pallas kernels must not capture tracers
 
-# static switch: largest [TQ, TR, TC] parent-candidate volume (bytes) we are
-# willing to materialize in VMEM before falling back to the per-query loop
-_PARENT_BCAST_BUDGET = 4 * 1024 * 1024
+
+def frontier_column(ft, q):
+    """Query ``q``'s frontier flags of this row tile as a bool [TR, 1]
+    column, read off the transposed [TR, TQ] frontier block by a masked
+    lane reduction."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, ft.shape, 1)
+    return jnp.max(jnp.where(lane == q, ft, 0.0), axis=1, keepdims=True) > 0
 
 
-def _multi_bfs_step_kernel(f_ref, adj_ref, alive_ref, visited_ref,
-                           reach_ref, parent_ref, *, tq: int, tr: int, tc: int,
-                           bcast_budget: int):
+def _multi_bfs_step_kernel(f_ref, ft_ref, adj_ref, alive_ref, visited_ref,
+                           reach_ref, parent_ref, *, tq: int, tr: int):
     r = pl.program_id(1)
     nr = pl.num_programs(1)
 
@@ -66,43 +79,38 @@ def _multi_bfs_step_kernel(f_ref, adj_ref, alive_ref, visited_ref,
         reach_ref[...] = jnp.zeros_like(reach_ref)
         parent_ref[...] = jnp.full_like(parent_ref, INT32_MAX)
 
-    f = f_ref[...]  # f32[TQ, TR] — all queries' slice of this row tile
+    ft = ft_ref[...]  # f32[TR, TQ] — the slab, transposed
 
-    @pl.when(jnp.any(f > 0))
+    @pl.when(jnp.max(ft) > 0)
     def _accumulate():
-        a = adj_ref[...].astype(jnp.float32)          # [TR, TC]
-        hits = jnp.dot(f, a, preferred_element_type=jnp.float32)  # MXU [TQ, TC]
-        reach_ref[...] = jnp.maximum(reach_ref[...], (hits > 0).astype(jnp.int32))
-        row_ids = r * tr + jax.lax.iota(jnp.int32, tr)            # global rows
-        if tq * tr * tc * 4 <= bcast_budget:
-            cand = jnp.where((f[:, :, None] > 0) & (a[None, :, :] > 0),
-                             row_ids[None, :, None], INT32_MAX)
-            cand_min = jnp.min(cand, axis=1)                      # [TQ, TC]
-        else:
-            def qrow(qi, acc):
-                fq = jax.lax.dynamic_slice_in_dim(f, qi, 1, axis=0)[0]
-                c = jnp.where((fq[:, None] > 0) & (a > 0),
-                              row_ids[:, None], INT32_MAX)
-                return jax.lax.dynamic_update_slice_in_dim(
-                    acc, jnp.min(c, axis=0)[None, :], qi, axis=0)
-            cand_min = jax.lax.fori_loop(
-                0, tq, qrow, jnp.full((tq, tc), INT32_MAX, jnp.int32))
-        parent_ref[...] = jnp.minimum(parent_ref[...], cand_min)
+        # raw tile: liveness is masked in the epilogue (alive & ~visited)
+        edge = adj_ref[...].astype(jnp.int32) > 0  # repro-lint: allow(traversable-predicate)
+        hits = jnp.dot(f_ref[...], edge.astype(jnp.float32),
+                       preferred_element_type=jnp.float32)     # MXU [TQ, TC]
+        reach_ref[...] = jnp.maximum(reach_ref[...],
+                                     (hits > 0).astype(jnp.int32))
+        rows = r * tr + jax.lax.broadcasted_iota(jnp.int32, edge.shape, 0)
+
+        def per_query(q, carry):
+            cand = jnp.where(frontier_column(ft, q) & edge, rows, INT32_MAX)
+            cur = parent_ref[pl.ds(q, 1), :]
+            parent_ref[pl.ds(q, 1), :] = jnp.minimum(
+                cur, jnp.min(cand, axis=0, keepdims=True))
+            return carry
+
+        jax.lax.fori_loop(0, tq, per_query, 0)
 
     @pl.when(r == nr - 1)
     def _epilogue():
-        new = ((reach_ref[...] > 0) & (alive_ref[...][None, :] > 0)
+        new = ((reach_ref[...] > 0) & (alive_ref[...] > 0)
                & (visited_ref[...] == 0))
         reach_ref[...] = new.astype(jnp.int32)
         parent_ref[...] = jnp.where(new, parent_ref[...], jnp.int32(-1))
 
 
-@functools.partial(
-    jax.jit, static_argnames=("tr", "tc", "interpret", "parent_bcast_budget")
-)
+@functools.partial(jax.jit, static_argnames=("tr", "tc", "interpret"))
 def multi_bfs_step_pallas(frontiers, adj, alive, visited, *, tr: int = 256,
-                          tc: int = 256, interpret: bool = True,
-                          parent_bcast_budget: int = _PARENT_BCAST_BUDGET):
+                          tc: int = 256, interpret: bool | None = None):
     """One fused expansion of Q frontiers. R % tr == 0 and V % tc == 0.
 
     frontiers: f32[Q, R] (0/1)   adj: int8/uint8[R, V]
@@ -116,9 +124,6 @@ def multi_bfs_step_pallas(frontiers, adj, alive, visited, *, tr: int = 256,
 
     Q is the full (already padded) query-slab height; callers align it to
     the f32 sublane multiple (kernels/bfs_multi_step/ops.py pads).
-    ``parent_bcast_budget`` is static (part of the jit/trace key) so the
-    parent-extraction strategy is pinned per compilation — pass 0 to force
-    the per-query fori_loop path.
     """
     q, rows = frontiers.shape
     v = adj.shape[1]
@@ -128,13 +133,13 @@ def multi_bfs_step_pallas(frontiers, adj, alive, visited, *, tr: int = 256,
     assert rows % tr == 0 and v % tc == 0, (rows, v, tr, tc)
     grid = (v // tc, rows // tr)
     return pl.pallas_call(
-        functools.partial(_multi_bfs_step_kernel, tq=q, tr=tr, tc=tc,
-                          bcast_budget=parent_bcast_budget),
+        functools.partial(_multi_bfs_step_kernel, tq=q, tr=tr),
         grid=grid,
         in_specs=[
             pl.BlockSpec((q, tr), lambda c, r: (0, r)),
+            pl.BlockSpec((tr, q), lambda c, r: (r, 0)),
             pl.BlockSpec((tr, tc), lambda c, r: (r, c)),
-            pl.BlockSpec((tc,), lambda c, r: (c,)),
+            pl.BlockSpec((1, tc), lambda c, r: (0, c)),
             pl.BlockSpec((q, tc), lambda c, r: (0, c)),
         ],
         out_specs=[
@@ -145,70 +150,70 @@ def multi_bfs_step_pallas(frontiers, adj, alive, visited, *, tr: int = 256,
             jax.ShapeDtypeStruct((q, v), jnp.int32),
             jax.ShapeDtypeStruct((q, v), jnp.int32),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        ) if not interpret else None,
-        interpret=interpret,
-    )(frontiers, adj, alive, visited)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_mode(interpret),
+    )(frontiers, frontiers.T, adj, alive[None, :], visited)
 
 
 # ----------------------------------------------------------------------------
 # Packed-word variant (DESIGN.md §10)
 # ----------------------------------------------------------------------------
-def _multi_bfs_step_packed_kernel(f_ref, adjw_ref, alive_ref, visited_ref,
-                                  reach_ref, parent_ref, words_ref, *,
-                                  tq: int, tr: int, tw: int,
-                                  bcast_budget: int):
+def _multi_bfs_step_packed_kernel(ft_ref, adjw_ref, alivew_ref, visw_ref,
+                                  neww_ref, parent_ref, reachw_ref, *,
+                                  tq: int, tr: int):
     r = pl.program_id(1)
     nr = pl.num_programs(1)
-    tc = tw * WORD_BITS
 
     @pl.when(r == 0)
     def _init():
-        words_ref[...] = jnp.zeros_like(words_ref)
-        reach_ref[...] = jnp.zeros_like(reach_ref)
+        reachw_ref[...] = jnp.zeros_like(reachw_ref)
         parent_ref[...] = jnp.full_like(parent_ref, INT32_MAX)
 
-    f = f_ref[...]  # f32[TQ, TR] — all queries' slice of this row tile
+    ft = ft_ref[...]  # f32[TR, TQ] — the slab, transposed
 
-    @pl.when(jnp.any(f > 0))
+    @pl.when(jnp.max(ft) > 0)
     def _accumulate():
-        a = adjw_ref[...]                               # uint32[TR, TW]
-        sel = jnp.where(f[:, :, None] > 0, a[None, :, :], jnp.uint32(0))
-        words_ref[...] |= or_reduce(sel, 1)             # [TQ, TW] OR fold
-        bits = unpack_bits(a, tc)                       # in-register unpack
-        row_ids = r * tr + jax.lax.iota(jnp.int32, tr)
-        if tq * tr * tc * 4 <= bcast_budget:
-            cand = jnp.where((f[:, :, None] > 0) & bits[None, :, :],
-                             row_ids[None, :, None], INT32_MAX)
-            cand_min = jnp.min(cand, axis=1)            # [TQ, TC]
-        else:
-            def qrow(qi, acc):
-                fq = jax.lax.dynamic_slice_in_dim(f, qi, 1, axis=0)[0]
-                c = jnp.where((fq[:, None] > 0) & bits,
-                              row_ids[:, None], INT32_MAX)
-                return jax.lax.dynamic_update_slice_in_dim(
-                    acc, jnp.min(c, axis=0)[None, :], qi, axis=0)
-            cand_min = jax.lax.fori_loop(
-                0, tq, qrow, jnp.full((tq, tc), INT32_MAX, jnp.int32))
-        parent_ref[...] = jnp.minimum(parent_ref[...], cand_min)
+        a = adjw_ref[...]                                       # u32[TR, TW]
+        rows = r * tr + jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+
+        def per_query(q, carry):
+            sel = jnp.where(frontier_column(ft, q), a, jnp.uint32(0))
+            words = jnp.zeros((1, a.shape[1]), jnp.uint32)
+            for b in range(WORD_BITS):                  # bit-major columns
+                hit = ((sel >> b) & jnp.uint32(1)) != 0
+                pm = jnp.min(jnp.where(hit, rows, INT32_MAX), axis=0,
+                             keepdims=True)             # [1, TW]
+                row = pl.ds(q * WORD_BITS + b, 1)
+                parent_ref[row, :] = jnp.minimum(parent_ref[row, :], pm)
+                words = words | jnp.where(pm < INT32_MAX,
+                                          jnp.uint32(1 << b), jnp.uint32(0))
+            qrow = pl.ds(q, 1)
+            reachw_ref[qrow, :] = reachw_ref[qrow, :] | words
+            return carry
+
+        jax.lax.fori_loop(0, tq, per_query, 0)
 
     @pl.when(r == nr - 1)
     def _epilogue():
-        reach = unpack_bits(words_ref[...], tc)
-        new = (reach & (alive_ref[...][None, :] > 0)
-               & (visited_ref[...] == 0))
-        reach_ref[...] = new.astype(jnp.int32)
-        parent_ref[...] = jnp.where(new, parent_ref[...], jnp.int32(-1))
+        neww_ref[...] = reachw_ref[...] & alivew_ref[...] & ~visw_ref[...]
+
+        def mask_query(q, carry):
+            nq = neww_ref[pl.ds(q, 1), :]
+            for b in range(WORD_BITS):
+                row = pl.ds(q * WORD_BITS + b, 1)
+                parent_ref[row, :] = jnp.where(
+                    ((nq >> b) & jnp.uint32(1)) != 0, parent_ref[row, :],
+                    jnp.int32(-1))
+            return carry
+
+        jax.lax.fori_loop(0, tq, mask_query, 0)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("tr", "tw", "interpret", "parent_bcast_budget")
-)
+@functools.partial(jax.jit, static_argnames=("tr", "tw", "interpret"))
 def multi_bfs_step_packed_pallas(frontiers, adj_packed, alive, visited, *,
-                                 tr: int = 256, tw: int = 8,
-                                 interpret: bool = True,
-                                 parent_bcast_budget: int = _PARENT_BCAST_BUDGET):
+                                 tr: int = 256, tw: int = 128,
+                                 interpret: bool | None = None):
     """One packed fused expansion of Q frontiers. R % tr == 0, W % tw == 0.
 
     frontiers: f32[Q, R] (0/1)   adj_packed: uint32[R, W]
@@ -227,30 +232,31 @@ def multi_bfs_step_packed_pallas(frontiers, adj_packed, alive, visited, *,
     assert alive.shape == (vc,) and visited.shape == (q, vc), \
         (alive.shape, visited.shape, vc)
     assert rows % tr == 0 and w % tw == 0, (rows, w, tr, tw)
-    tc = tw * WORD_BITS
     grid = (w // tw, rows // tr)
-    return pl.pallas_call(
-        functools.partial(_multi_bfs_step_packed_kernel, tq=q, tr=tr, tw=tw,
-                          bcast_budget=parent_bcast_budget),
+    new_w, parent_bm, reach_w = pl.pallas_call(
+        functools.partial(_multi_bfs_step_packed_kernel, tq=q, tr=tr),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((q, tr), lambda c, r: (0, r)),
+            pl.BlockSpec((tr, q), lambda c, r: (r, 0)),
             pl.BlockSpec((tr, tw), lambda c, r: (r, c)),
-            pl.BlockSpec((tc,), lambda c, r: (c,)),
-            pl.BlockSpec((q, tc), lambda c, r: (0, c)),
+            pl.BlockSpec((1, tw), lambda c, r: (0, c)),
+            pl.BlockSpec((q, tw), lambda c, r: (0, c)),
         ],
         out_specs=[
-            pl.BlockSpec((q, tc), lambda c, r: (0, c)),
-            pl.BlockSpec((q, tc), lambda c, r: (0, c)),
+            pl.BlockSpec((q, tw), lambda c, r: (0, c)),
+            pl.BlockSpec((q * WORD_BITS, tw), lambda c, r: (0, c)),
             pl.BlockSpec((q, tw), lambda c, r: (0, c)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q, vc), jnp.int32),
-            jax.ShapeDtypeStruct((q, vc), jnp.int32),
+            jax.ShapeDtypeStruct((q, w), jnp.uint32),
+            jax.ShapeDtypeStruct((q * WORD_BITS, w), jnp.int32),
             jax.ShapeDtypeStruct((q, w), jnp.uint32),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        ) if not interpret else None,
-        interpret=interpret,
-    )(frontiers, adj_packed, alive, visited)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_mode(interpret),
+    )(frontiers.T, adj_packed, pack_bits(alive > 0)[None, :],
+      pack_bits(visited > 0))
+    parent = parent_bm.reshape(q, WORD_BITS, w).transpose(0, 2, 1)
+    return (unpack_bits(new_w, vc).astype(jnp.int32),
+            parent.reshape(q, vc), reach_w)

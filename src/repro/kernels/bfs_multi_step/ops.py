@@ -17,7 +17,7 @@ from repro.kernels.bfs_multi_step.kernel import (
     multi_bfs_step_packed_pallas,
     multi_bfs_step_pallas,
 )
-from repro.kernels.bfs_step.ops import _pick_tile, _pick_word_tile
+from repro.kernels.mosaic import pick_row_tile, pick_word_tile
 
 _Q_ALIGN = 8  # f32 sublane multiple
 
@@ -36,8 +36,8 @@ def multi_bfs_step(frontiers, adj, alive, visited):
     q, rows = frontiers.shape
     v = adj.shape[1]
     qpad = -(-q // _Q_ALIGN) * _Q_ALIGN
-    tr = _pick_tile(rows)
-    tc = _pick_tile(v)
+    tr = pick_row_tile(rows)
+    tc = pick_row_tile(v)
     f = jnp.zeros((qpad, rows), jnp.float32).at[:q].set(frontiers.astype(jnp.float32))
     vis = jnp.zeros((qpad, v), jnp.int32).at[:q].set(visited.astype(jnp.int32))
     new, parent = multi_bfs_step_pallas(
@@ -47,7 +47,6 @@ def multi_bfs_step(frontiers, adj, alive, visited):
         vis,
         tr=tr,
         tc=tc,
-        interpret=True,  # CPU container; on TPU set interpret=False
     )
     return new[:q] > 0, parent[:q]
 
@@ -78,8 +77,7 @@ def multi_bfs_step_packed(frontiers, adj_packed, alive, visited):
         adj_packed,
         alive_p,
         vis_p,
-        tr=_pick_tile(rows),
-        tw=_pick_word_tile(w),
-        interpret=True,  # CPU container; on TPU set interpret=False
+        tr=pick_row_tile(rows),
+        tw=pick_word_tile(w),
     )
     return new[:q, :v] > 0, parent[:q, :v]

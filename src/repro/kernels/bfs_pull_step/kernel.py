@@ -30,13 +30,19 @@ words IS the per-word early exit — the scan effectively stops at the first
 word containing a parent. ctz comes from the two's-complement low-bit
 trick (x & -x, then popcount(x-1)); both verified native on uint32.
 
-VMEM footprint per program instance (TQ=8, TR=256, W=32 ⇒ V=1024):
-    adj_in tile    256*32 u32      =  32 KiB
-    frontier slab  8*32 u32        =   1 KiB
-    candidate cube 8*256*32 u32    = 256 KiB        << 16 MiB VMEM
-Larger (TQ * TR * W) volumes fall back to a fori_loop over query rows
-holding one [TR, W] slice at a time — the same static budget switch as
-kernels/bfs_multi_step.
+Layout: a destination row's answer is a lane reduction over its words, so
+it lands in a [TR, 1] sublane column. The kernel therefore works on the
+TRANSPOSED slabs — the "still to visit" mask and both outputs are [R, TQ]
+(rows on sublanes, queries on lanes) — and writes query q's column with a
+lane-masked select; the wrapper transposes to the [Q, R] contract. Every
+block is 2-D with a whole-axis or 128-multiple last dim (Mosaic's rule),
+and the per-query ``fori_loop`` keeps one [TR, W] candidate slice live.
+
+VMEM footprint per program instance (TQ=64, TR=256, W=1024 => V=32768):
+    adj_in tile    256*1024 u32    =   1 MiB
+    frontier slab  64*1024 u32     = 256 KiB
+    todo/out slabs 3 * 256*64 i32  = 192 KiB
+    candidate temps ~3 * 1 MiB                       << 16 MiB VMEM
 """
 from __future__ import annotations
 
@@ -45,14 +51,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.graph import WORD_BITS
+from repro.kernels.mosaic import interpret_mode
 
 INT32_MAX = 2**31 - 1  # python int: pallas kernels must not capture tracers
-
-# static switch: largest [TQ, TR, W] pull-candidate volume (bytes) we are
-# willing to materialize in VMEM before falling back to the per-query loop
-_PULL_BCAST_BUDGET = 4 * 1024 * 1024
 
 
 def _ctz32(words):
@@ -62,53 +66,42 @@ def _ctz32(words):
     return jax.lax.population_count(low - jnp.uint32(1)).astype(jnp.int32)
 
 
-def _bfs_pull_step_kernel(fw_ref, adjin_ref, alive_ref, visited_ref,
-                          new_ref, parent_ref, *, tq: int, tr: int, w: int,
-                          bcast_budget: int):
+def _bfs_pull_step_kernel(fw_ref, adjin_ref, todo_ref, new_ref, parent_ref,
+                          *, tq: int):
     new_ref[...] = jnp.zeros_like(new_ref)
     parent_ref[...] = jnp.full_like(parent_ref, -1)
 
-    fw = fw_ref[...]                                   # uint32 [TQ, W]
-    todo = (alive_ref[...][None, :] > 0) & (visited_ref[...] == 0)  # [TQ, TR]
+    todo = todo_ref[...]                               # int32 [TR, TQ]
+    fw_any = jnp.max((fw_ref[...] != jnp.uint32(0)).astype(jnp.int32))
 
-    @pl.when(jnp.any(todo) & jnp.any(fw != 0))
+    @pl.when((jnp.max(todo) > 0) & (fw_any > 0))
     def _scan():
         a = adjin_ref[...]                             # uint32 [TR, W]
-        widx = jax.lax.iota(jnp.int32, w) * WORD_BITS  # global bit bases
-        if tq * tr * w * 4 <= bcast_budget:
-            cand = a[None, :, :] & fw[:, None, :]      # [TQ, TR, W]
-            nz = cand != jnp.uint32(0)
-            pc = jnp.where(nz, widx[None, None, :] + _ctz32(cand), INT32_MAX)
-            pmin = jnp.min(pc, axis=2)                 # [TQ, TR]
-            hit = jnp.any(nz, axis=2)
-        else:
-            def qrow(qi, acc):
-                pm, ht = acc
-                fq = jax.lax.dynamic_slice_in_dim(fw, qi, 1, axis=0)[0]
-                c = a & fq[None, :]                    # [TR, W]
-                nzq = c != jnp.uint32(0)
-                pcq = jnp.where(nzq, widx[None, :] + _ctz32(c), INT32_MAX)
-                pm = jax.lax.dynamic_update_slice_in_dim(
-                    pm, jnp.min(pcq, axis=1)[None, :], qi, axis=0)
-                ht = jax.lax.dynamic_update_slice_in_dim(
-                    ht, jnp.any(nzq, axis=1)[None, :], qi, axis=0)
-                return pm, ht
+        widx = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1) * WORD_BITS
+        lane = jax.lax.broadcasted_iota(jnp.int32, todo.shape, 1)
 
-            pmin, hit = jax.lax.fori_loop(
-                0, tq, qrow,
-                (jnp.full((tq, tr), INT32_MAX, jnp.int32),
-                 jnp.zeros((tq, tr), jnp.bool_)))
-        new = hit & todo
+        def per_query(q, carry):
+            hit, pmin = carry
+            cand = a & fw_ref[pl.ds(q, 1), :]          # [TR, W]
+            pc = jnp.where(cand != jnp.uint32(0), widx + _ctz32(cand),
+                           INT32_MAX)
+            pq = jnp.min(pc, axis=1, keepdims=True)    # [TR, 1]
+            mine = lane == q
+            return (jnp.where(mine, (pq < INT32_MAX).astype(jnp.int32), hit),
+                    jnp.where(mine, pq, pmin))
+
+        hit, pmin = jax.lax.fori_loop(
+            0, tq, per_query,
+            (jnp.zeros(todo.shape, jnp.int32),
+             jnp.full(todo.shape, INT32_MAX, jnp.int32)))
+        new = (hit > 0) & (todo > 0)
         new_ref[...] = new.astype(jnp.int32)
         parent_ref[...] = jnp.where(new, pmin, jnp.int32(-1))
 
 
-@functools.partial(
-    jax.jit, static_argnames=("tr", "interpret", "pull_bcast_budget")
-)
+@functools.partial(jax.jit, static_argnames=("tr", "interpret"))
 def bfs_pull_step_pallas(frontier_words, adj_in_rows, alive, visited, *,
-                         tr: int = 256, interpret: bool = True,
-                         pull_bcast_budget: int = _PULL_BCAST_BUDGET):
+                         tr: int = 256, interpret: bool | None = None):
     """One pull expansion of Q frontiers over R destination rows. R % tr == 0.
 
     frontier_words: uint32[Q, W] — packed (frontier & alive) bitsets
@@ -125,9 +118,6 @@ def bfs_pull_step_pallas(frontier_words, adj_in_rows, alive, visited, *,
 
     Q is the full (already padded) query-slab height; callers align it to
     the sublane multiple (kernels/bfs_pull_step/ops.py pads).
-    ``pull_bcast_budget`` is static (part of the jit key), pinning the
-    candidate-volume strategy per compilation; pass 0 to force the
-    per-query fori_loop path.
     """
     q, w = frontier_words.shape
     r = adj_in_rows.shape[0]
@@ -135,27 +125,26 @@ def bfs_pull_step_pallas(frontier_words, adj_in_rows, alive, visited, *,
     assert alive.shape == (r,) and visited.shape == (q, r), \
         (alive.shape, visited.shape, (q, r))
     assert r % tr == 0, (r, tr)
+    todo = ((alive[None, :] > 0) & (visited == 0)).astype(jnp.int32)
     grid = (r // tr,)
-    return pl.pallas_call(
-        functools.partial(_bfs_pull_step_kernel, tq=q, tr=tr, w=w,
-                          bcast_budget=pull_bcast_budget),
+    new_t, parent_t = pl.pallas_call(
+        functools.partial(_bfs_pull_step_kernel, tq=q),
         grid=grid,
         in_specs=[
             pl.BlockSpec((q, w), lambda i: (0, 0)),
             pl.BlockSpec((tr, w), lambda i: (i, 0)),
-            pl.BlockSpec((tr,), lambda i: (i,)),
-            pl.BlockSpec((q, tr), lambda i: (0, i)),
+            pl.BlockSpec((tr, q), lambda i: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((q, tr), lambda i: (0, i)),
-            pl.BlockSpec((q, tr), lambda i: (0, i)),
+            pl.BlockSpec((tr, q), lambda i: (i, 0)),
+            pl.BlockSpec((tr, q), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q, r), jnp.int32),
-            jax.ShapeDtypeStruct((q, r), jnp.int32),
+            jax.ShapeDtypeStruct((r, q), jnp.int32),
+            jax.ShapeDtypeStruct((r, q), jnp.int32),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel",))
-        ) if not interpret else None,
-        interpret=interpret,
-    )(frontier_words, adj_in_rows, alive, visited)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret_mode(interpret),
+    )(frontier_words, adj_in_rows, todo.T)
+    return new_t.T, parent_t.T

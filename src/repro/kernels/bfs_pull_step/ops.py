@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from repro.core.graph import pack_bits
 from repro.kernels.bfs_pull_step.kernel import bfs_pull_step_pallas
-from repro.kernels.bfs_step.ops import _pick_tile
+from repro.kernels.mosaic import pick_row_tile
 
 _Q_ALIGN = 8  # sublane multiple for the 32-bit slabs
 
@@ -43,8 +43,7 @@ def multi_bfs_pull_step_rows(frontier_words, adj_in_rows, alive_rows,
         adj_in_rows,
         alive_rows.astype(jnp.int32),
         visp,
-        tr=_pick_tile(rows),
-        interpret=True,  # CPU container; on TPU set interpret=False
+        tr=pick_row_tile(rows),
     )
     return new[:q] > 0, parent[:q]
 

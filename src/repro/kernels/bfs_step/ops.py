@@ -1,4 +1,12 @@
-"""jit'd public wrappers for the bfs_step kernels (adapt GraphState dtypes)."""
+"""Single-query BFS superstep on the Pallas kernels (adapts GraphState dtypes).
+
+One frontier is the Q=1 case of the fused multi-query superstep
+(kernels/bfs_multi_step, DESIGN.md §7): every entry point here runs that
+kernel on a one-query slab zero-padded to the 8-row f32 sublane tile. The
+padded rows carry empty frontiers, which the kernel's @pl.when tile skip
+and per-query loop never expand, so one kernel body per adjacency encoding
+holds all the Mosaic layout work.
+"""
 from __future__ import annotations
 
 import functools
@@ -7,21 +15,37 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.graph import WORD_BITS
-from repro.kernels.bfs_step.kernel import bfs_step_packed_pallas, bfs_step_pallas
+from repro.kernels.bfs_multi_step.kernel import (
+    multi_bfs_step_packed_pallas,
+    multi_bfs_step_pallas,
+)
+from repro.kernels.mosaic import pick_row_tile, pick_word_tile
+
+_SLAB = 8  # f32 sublane multiple: the one-query slab is padded to 8 rows
 
 
-def _pick_tile(v: int) -> int:
-    for t in (256, 128, 64, 32, 16, 8):
-        if v % t == 0:
-            return t
-    return v
+def _slab(frontier):
+    return jnp.zeros((_SLAB,) + frontier.shape, jnp.float32).at[0].set(
+        frontier.astype(jnp.float32))
 
 
-def _pick_word_tile(w: int) -> int:
-    for t in (64, 32, 16, 8, 4, 2):
-        if w % t == 0:
-            return t
-    return w
+def _visited_slab(visited):
+    return jnp.zeros((_SLAB,) + visited.shape, jnp.int32).at[0].set(
+        visited.astype(jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("tr", "tc"))
+def bfs_step_pallas(frontier, adj, alive, visited, *, tr: int = 256,
+                    tc: int = 256):
+    """One frontier expansion. All inputs length-V / VxV, V % max(tr,tc) == 0.
+
+    frontier: f32[V] (0/1)   adj: int8/uint8[V, V]
+    alive:    int32[V] (0/1) visited: int32[V] (0/1)
+    Returns (new_frontier int32[V], parent int32[V]).
+    """
+    new, parent = multi_bfs_step_pallas(
+        _slab(frontier), adj, alive, _visited_slab(visited), tr=tr, tc=tc)
+    return new[0], parent[0]
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -31,17 +55,9 @@ def bfs_step(frontier, adj, alive, visited):
     frontier/alive/visited: bool[V]; adj: uint8[V, V]
     -> (new_frontier bool[V], parent int32[V])
     """
-    v = adj.shape[0]
-    t = _pick_tile(v)
+    t = pick_row_tile(adj.shape[0])
     new, parent = bfs_step_pallas(
-        frontier.astype(jnp.float32),
-        adj,
-        alive.astype(jnp.int32),
-        visited.astype(jnp.int32),
-        tr=t,
-        tc=t,
-        interpret=True,  # CPU container; on TPU set interpret=False
-    )
+        frontier, adj, alive.astype(jnp.int32), visited, tr=t, tc=t)
     return new > 0, parent
 
 
@@ -60,13 +76,7 @@ def bfs_step_packed(frontier, adj_packed, alive, visited):
     vc = w * WORD_BITS
     alive_p = jnp.zeros((vc,), jnp.int32).at[:v].set(alive.astype(jnp.int32))
     vis_p = jnp.zeros((vc,), jnp.int32).at[:v].set(visited.astype(jnp.int32))
-    new, parent, _words = bfs_step_packed_pallas(
-        frontier.astype(jnp.float32),
-        adj_packed,
-        alive_p,
-        vis_p,
-        tr=_pick_tile(v),
-        tw=_pick_word_tile(w),
-        interpret=True,  # CPU container; on TPU set interpret=False
-    )
-    return new[:v] > 0, parent[:v]
+    new, parent, _words = multi_bfs_step_packed_pallas(
+        _slab(frontier), adj_packed, alive_p, _visited_slab(vis_p),
+        tr=pick_row_tile(v), tw=pick_word_tile(w))
+    return new[0, :v] > 0, parent[0, :v]

@@ -1,16 +1,13 @@
-"""Pure-jnp oracles for the bfs_step kernels (dense and packed)."""
+"""Pure-jnp oracle for the single-query BFS superstep."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-
-from repro.core.graph import WORD_BITS, unpack_bits
 
 INT32_MAX = jnp.int32(2**31 - 1)
 
 
 def bfs_step_ref(frontier, adj, alive, visited):
-    """Same contract as kernel.bfs_step_pallas.
+    """Same contract as ops.bfs_step_pallas.
 
     frontier f32[V] (0/1), adj (u)int8[V,V], alive/visited int32[V] (0/1)
     -> (new_frontier int32[V], parent int32[V]).
@@ -27,24 +24,3 @@ def bfs_step_ref(frontier, adj, alive, visited):
     parent = jnp.min(cand, axis=0)
     parent = jnp.where(new, parent, jnp.int32(-1))
     return new.astype(jnp.int32), parent
-
-
-def bfs_step_packed_ref(frontier, adj_packed, alive, visited):
-    """Same contract as kernel.bfs_step_packed_pallas (unpack-then-dense-ref).
-
-    frontier f32[V] (0/1), adj_packed uint32[V, W], alive/visited
-    int32[W*32] (0/1) -> (new int32[W*32], parent int32[W*32],
-    reach_words uint32[W]).
-    """
-    v, w = adj_packed.shape
-    vc = w * WORD_BITS
-    adj = unpack_bits(adj_packed, vc).astype(jnp.uint8)
-    fp = jnp.zeros((vc,), jnp.float32).at[:v].set(frontier.astype(jnp.float32))
-    adj_p = jnp.zeros((vc, vc), jnp.uint8).at[:v].set(adj)
-    new, parent = bfs_step_ref(fp, adj_p, alive, visited)
-    # raw pre-mask OR partial: reach_words deliberately carries physical
-    # reachability (DESIGN.md §10)  # repro-lint: allow(traversable-predicate)
-    reach = (fp @ adj_p.astype(jnp.float32)) > 0
-    from repro.core.graph import pack_bits
-
-    return new, parent, pack_bits(reach)

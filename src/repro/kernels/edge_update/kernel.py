@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mosaic import interpret_mode
+
 
 def _edge_update_kernel(rows_ref, cols_ref, vals_ref, mask_ref, adj_in_ref,
                         ecnt_in_ref, adj_ref, ecnt_ref, *, tr: int):
@@ -54,7 +56,8 @@ def _edge_update_kernel(rows_ref, cols_ref, vals_ref, mask_ref, adj_in_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("tr", "interpret"))
-def edge_update_pallas(adj, ecnt, rows, cols, vals, mask, *, tr: int = 8, interpret: bool = True):
+def edge_update_pallas(adj, ecnt, rows, cols, vals, mask, *, tr: int = 8,
+                       interpret: bool | None = None):
     """adj uint8[V,V], ecnt int32[V]; rows/cols/vals/mask int32[B].
 
     Returns (adj', ecnt'). Rows with mask==0 are ignored. Fired ops must have
@@ -82,7 +85,7 @@ def edge_update_pallas(adj, ecnt, rows, cols, vals, mask, *, tr: int = 8, interp
             jax.ShapeDtypeStruct(adj.shape, adj.dtype),
             jax.ShapeDtypeStruct(ecnt.shape, ecnt.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(rows, cols, vals, mask, adj, ecnt)
 
 
@@ -125,7 +128,7 @@ def _edge_update_packed_kernel(rows_ref, cols_ref, vals_ref, mask_ref,
 
 @functools.partial(jax.jit, static_argnames=("tr", "interpret"))
 def edge_update_packed_pallas(adj_packed, ecnt, rows, cols, vals, mask, *,
-                              tr: int = 8, interpret: bool = True):
+                              tr: int = 8, interpret: bool | None = None):
     """adj_packed uint32[V, W], ecnt int32[V]; rows/cols/vals/mask int32[B].
 
     Returns (adj_packed', ecnt'). Same lane-order last-wins semantics as the
@@ -153,5 +156,5 @@ def edge_update_packed_pallas(adj_packed, ecnt, rows, cols, vals, mask, *,
             jax.ShapeDtypeStruct(adj_packed.shape, adj_packed.dtype),
             jax.ShapeDtypeStruct(ecnt.shape, ecnt.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(rows, cols, vals, mask, adj_packed, ecnt)

@@ -27,7 +27,7 @@ def edge_update(adj, ecnt, rows, cols, vals, mask):
         adj, ecnt,
         rows.astype(jnp.int32), cols.astype(jnp.int32),
         vals.astype(jnp.int32), mask.astype(jnp.int32),
-        tr=t, interpret=True,  # CPU container; on TPU set interpret=False
+        tr=t,
     )
 
 
@@ -42,5 +42,5 @@ def edge_update_packed(adj_packed, ecnt, rows, cols, vals, mask):
         adj_packed, ecnt,
         rows.astype(jnp.int32), cols.astype(jnp.int32),
         vals.astype(jnp.int32), mask.astype(jnp.int32),
-        tr=t, interpret=True,  # CPU container; on TPU set interpret=False
+        tr=t,
     )

@@ -28,8 +28,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.graph import WORD_BITS
+from repro.kernels.mosaic import interpret_mode
 
 INT32_MAX = 2**31 - 1  # python int: pallas kernels must not capture tracers
 
@@ -64,7 +66,7 @@ def _label_join_kernel(out_ref, in_ref, hits_ref, hub_ref, *, tl: int):
 
 @functools.partial(jax.jit, static_argnames=("tq", "tl", "interpret"))
 def label_join_pallas(out_rows, in_rows, *, tq: int = 256, tl: int = 256,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """Batched label intersection. Q % tq == 0 and L % tl == 0.
 
     out_rows: int32[Q, L] (0/1)   in_rows: int32[Q, L] (0/1)
@@ -92,10 +94,9 @@ def label_join_pallas(out_rows, in_rows, *, tq: int = 256, tl: int = 256,
             jax.ShapeDtypeStruct((q,), jnp.int32),
             jax.ShapeDtypeStruct((q,), jnp.int32),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        ) if not interpret else None,
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_mode(interpret),
     )(out_rows, in_rows)
 
 
@@ -115,17 +116,20 @@ def _label_join_packed_kernel(out_ref, in_ref, hits_ref, hub_ref, *, tw: int):
 
     a = out_ref[...]  # uint32[TQ, TW]
 
-    @pl.when(jnp.any(a > 0))
+    @pl.when(jnp.max((a != jnp.uint32(0)).astype(jnp.int32)) > 0)
     def _accumulate():
         common = a & in_ref[...]
         hits_ref[...] += jnp.sum(
-            jax.lax.population_count(common).astype(jnp.int32), axis=1)
+            jax.lax.population_count(common).astype(jnp.int32), axis=1,
+            keepdims=True)
         # smallest set bit per word: ctz(x) = popcount(lowbit(x) - 1)
         low = common & (jnp.uint32(0) - common)
         ctz = jax.lax.population_count(low - jnp.uint32(1)).astype(jnp.int32)
-        lane0 = (li * tw + jax.lax.iota(jnp.int32, tw)) * WORD_BITS
-        cand = jnp.where(common > 0, lane0[None, :] + ctz, INT32_MAX)
-        hub_ref[...] = jnp.minimum(hub_ref[...], jnp.min(cand, axis=1))
+        lane0 = (li * tw + jax.lax.broadcasted_iota(
+            jnp.int32, common.shape, 1)) * WORD_BITS
+        cand = jnp.where(common != jnp.uint32(0), lane0 + ctz, INT32_MAX)
+        hub_ref[...] = jnp.minimum(hub_ref[...],
+                                   jnp.min(cand, axis=1, keepdims=True))
 
     @pl.when(li == nl - 1)
     def _epilogue():
@@ -135,19 +139,20 @@ def _label_join_packed_kernel(out_ref, in_ref, hits_ref, hub_ref, *, tw: int):
 
 @functools.partial(jax.jit, static_argnames=("tq", "tw", "interpret"))
 def label_join_packed_pallas(out_words, in_words, *, tq: int = 256,
-                             tw: int = 8, interpret: bool = True):
+                             tw: int = 128, interpret: bool | None = None):
     """Packed batched label intersection. Q % tq == 0 and W % tw == 0.
 
     out_words/in_words: uint32[Q, W] — packed OUT labels of the Q sources /
     IN labels of the Q destinations. Returns (hits int32[Q], hub int32[Q])
     with hub the smallest common landmark index (-1 when empty), identical
-    to the dense kernel on the unpacked labels.
+    to the dense kernel on the unpacked labels. Per-query answers are lane
+    reductions, so the kernel writes them as [Q, 1] columns.
     """
     q, w = out_words.shape
     assert in_words.shape == (q, w), (out_words.shape, in_words.shape)
     assert q % tq == 0 and w % tw == 0, (q, w, tq, tw)
     grid = (q // tq, w // tw)
-    return pl.pallas_call(
+    hits, hub = pl.pallas_call(
         functools.partial(_label_join_packed_kernel, tw=tw),
         grid=grid,
         in_specs=[
@@ -155,15 +160,15 @@ def label_join_packed_pallas(out_words, in_words, *, tq: int = 256,
             pl.BlockSpec((tq, tw), lambda qi, li: (qi, li)),
         ],
         out_specs=[
-            pl.BlockSpec((tq,), lambda qi, li: (qi,)),
-            pl.BlockSpec((tq,), lambda qi, li: (qi,)),
+            pl.BlockSpec((tq, 1), lambda qi, li: (qi, 0)),
+            pl.BlockSpec((tq, 1), lambda qi, li: (qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q,), jnp.int32),
-            jax.ShapeDtypeStruct((q,), jnp.int32),
+            jax.ShapeDtypeStruct((q, 1), jnp.int32),
+            jax.ShapeDtypeStruct((q, 1), jnp.int32),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        ) if not interpret else None,
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_mode(interpret),
     )(out_words, in_words)
+    return hits[:, 0], hub[:, 0]

@@ -5,7 +5,8 @@ Pure literal by contract (``ast.literal_eval`` is the parser). The packed
 variant reduces uint32 label words to dense int32 (hits, hub) outputs;
 its padding story is ``"slice"`` — the ops.py wrapper zero-extends padded
 queries in and slices ``[:q]`` back out, and zero padding bits contribute
-neither hits nor hub candidates (popcount/ctz of 0).
+neither hits nor hub candidates (popcount/ctz of 0). The packed kernel
+writes its per-query answers as [Q, 1] columns (lane reductions).
 """
 
 KERNEL_META = {
@@ -32,16 +33,16 @@ KERNEL_META = {
             "scratch_bytes": 0,
         },
         "label_join_packed_pallas": {
-            "tiles": {"tq": 256, "tw": 8},
-            "align": {"tq": 8, "tw": 8},
+            "tiles": {"tq": 256, "tw": 128},
+            "align": {"tq": 8, "tw": 128},
             "divides": {"q": ["tq"], "w": ["tw"]},
             "operands": {
                 "out_words": {"block": ["tq", "tw"], "dtype": "uint32"},
                 "in_words": {"block": ["tq", "tw"], "dtype": "uint32"},
             },
             "outputs": {
-                "hits": {"block": ["tq"], "dtype": "int32"},
-                "hub": {"block": ["tq"], "dtype": "int32"},
+                "hits": {"block": ["tq", 1], "dtype": "int32"},
+                "hub": {"block": ["tq", 1], "dtype": "int32"},
             },
             "packed": True,
             "pad_safety": "slice",

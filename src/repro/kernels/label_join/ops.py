@@ -18,7 +18,7 @@ from repro.kernels.label_join.kernel import (
     label_join_packed_pallas,
     label_join_pallas,
 )
-from repro.kernels.bfs_step.ops import _pick_tile, _pick_word_tile
+from repro.kernels.mosaic import pick_row_tile, pick_word_tile
 
 _Q_ALIGN = 8    # sublane multiple
 _L_ALIGN = 128  # lane multiple
@@ -44,9 +44,8 @@ def label_join(out_rows, in_rows):
     hits, hub = label_join_pallas(
         a,
         b,
-        tq=_pick_tile(qpad),
-        tl=_pick_tile(lpad),
-        interpret=True,  # CPU container; on TPU set interpret=False
+        tq=pick_row_tile(qpad),
+        tl=pick_row_tile(lpad),
     )
     return hits[:q], hub[:q]
 
@@ -69,8 +68,7 @@ def label_join_packed(out_words, in_words):
     hits, hub = label_join_packed_pallas(
         a,
         b,
-        tq=_pick_tile(qpad),
-        tw=_pick_word_tile(w),
-        interpret=True,  # CPU container; on TPU set interpret=False
+        tq=pick_row_tile(qpad),
+        tw=pick_word_tile(w),
     )
     return hits[:q], hub[:q]

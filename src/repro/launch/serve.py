@@ -24,6 +24,7 @@ import json
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import OP_ADD_E, OP_ADD_V
 from repro.models.model import build_model
@@ -70,6 +71,7 @@ def main():
     ap.add_argument("--retain-epochs", type=int, default=16,
                     help="epoch-ring retention window under --ingest")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

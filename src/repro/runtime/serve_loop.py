@@ -521,15 +521,19 @@ class GraphCoServer:
                                      "refreshes": int(self.index_refreshes)}
         return True
 
-    def get_reach(self, pairs: list, max_rounds: int = 64):
+    def get_reach(self, pairs: list, max_rounds: int = 64,
+                  join_backend: str = "jnp"):
         """Batched reachability WITHOUT paths — the read-heavy fast path.
         Index-served when fresh (answers linearize at the freshness check);
         stale epochs and undecided pairs transparently fall back to the
         fused BFS double collect. Returns a ``ReachSessionResult`` whose
-        ``.paths()`` lazily materializes witness paths on demand."""
+        ``.paths()`` lazily materializes witness paths on demand.
+        ``join_backend`` picks the label intersection: ``"jnp"`` or the
+        ``"pallas"`` label_join kernel (bit-identical answers)."""
         res = reach_session(lambda: self.state,
                             self.index if self.index_enabled else None,
                             pairs, engine=self.query_engine,
+                            join_backend=join_backend,
                             max_rounds=max_rounds,
                             on_conflict=self.on_conflict,
                             fetch_epoch=self._fetch_epoch(),

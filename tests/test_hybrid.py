@@ -102,9 +102,8 @@ def test_pull_kernel_matches_ref():
     alive = jnp.asarray(rng.random(r) < 0.8).astype(jnp.int32)
     vis = jnp.asarray(rng.random((q, r)) < 0.3).astype(jnp.int32)
     want = bfs_pull_step_ref(fw, adjin, alive, vis)
-    for budget in (None, 0):  # broadcast path and fori fallback path
-        kw = {} if budget is None else {"pull_bcast_budget": budget}
-        got = bfs_pull_step_pallas(fw, adjin, alive, vis, tr=32, **kw)
+    for tr in (32, 64):  # several row tiles, and one tile over all rows
+        got = bfs_pull_step_pallas(fw, adjin, alive, vis, tr=tr)
         for name, a, b in zip(("new", "parent"), got, want):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=name)

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core.graph import pack_bits
-from repro.kernels.bfs_step.kernel import bfs_step_pallas
+from repro.kernels.bfs_step.ops import bfs_step_pallas
 from repro.kernels.bfs_step.ops import bfs_step, bfs_step_packed
 from repro.kernels.bfs_step.ref import bfs_step_ref
 from repro.kernels.bfs_multi_step.kernel import multi_bfs_step_pallas
@@ -109,14 +109,13 @@ def test_multi_bfs_step_block_shapes(tr, tc):
 
 
 def test_multi_bfs_step_parent_loop_fallback():
-    """Large query slabs switch the parent masked-min to the per-query
-    fori_loop that bounds VMEM; both strategies must agree with the ref.
-    The budget is a static jit argument, so passing 0 pins this
-    compilation to the fori_loop path regardless of trace caching."""
+    """Parent extraction runs one query of the slab at a time (a fori_loop
+    that keeps one [TR, TC] candidate slice live, the VMEM bound); a
+    16-query slab over several row and column tiles must agree with the
+    ref."""
     f, adj, alive, visited = _multi_inputs(16, 128, 0.08)
     ref = multi_bfs_step_ref(f, adj, alive, visited)
-    out = multi_bfs_step_pallas(f, adj, alive, visited, tr=64, tc=64,
-                                parent_bcast_budget=0)
+    out = multi_bfs_step_pallas(f, adj, alive, visited, tr=64, tc=64)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]))
     np.testing.assert_allclose(np.asarray(out[1]), np.asarray(ref[1]))
 
@@ -280,3 +279,43 @@ def test_pallas_backend_full_bfs_matches_jnp():
         pp = get_path(g, s, d, backend="pallas")
         assert bool(pj.found) == bool(pp.found)
         np.testing.assert_array_equal(np.asarray(pj.keys), np.asarray(pp.keys))
+
+
+# ----------------------------------------------------------------------------
+# The one interpret decision (kernels/mosaic.py)
+# ----------------------------------------------------------------------------
+def test_interpret_mode_reads_the_platform(monkeypatch):
+    from repro.kernels.mosaic import interpret_mode
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert interpret_mode() is True
+    assert interpret_mode(False) is False   # an explicit choice wins
+    assert interpret_mode(True) is True
+
+
+def test_no_wrapper_hard_codes_interpret():
+    """No ops.py wrapper passes a literal ``interpret=True``, and every
+    kernel's ``interpret`` defaults to None (the platform decides)."""
+    import ast
+    import pathlib
+
+    import repro.kernels
+
+    root = pathlib.Path(repro.kernels.__file__).parent
+    ops_files = sorted(root.glob("*/ops.py"))
+    assert ops_files
+    for path in ops_files:
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.keyword) and node.arg == "interpret":
+                assert not (isinstance(node.value, ast.Constant)
+                            and node.value.value is True), path
+    for path in sorted(root.glob("*/kernel.py")):
+        for fn in ast.walk(ast.parse(path.read_text())):
+            if not isinstance(fn, ast.FunctionDef):
+                continue
+            for arg, default in zip(fn.args.kwonlyargs, fn.args.kw_defaults):
+                if arg.arg == "interpret":
+                    assert isinstance(default, ast.Constant)
+                    assert default.value is None, (path, fn.name)
