@@ -89,6 +89,14 @@ class EpochRecord:
     ecnt_xor: np.ndarray      # int32[K]
     adj_xor: np.ndarray       # uint32[K, W] — packed out-adjacency rows
 
+    @property
+    def nbytes(self) -> int:
+        """Host bytes the record holds."""
+        return (self.versions.nbytes + self.rows.nbytes
+                + self.vkey_xor.nbytes + self.valive_xor.nbytes
+                + self.vver_xor.nbytes + self.ecnt_xor.nbytes
+                + self.adj_xor.nbytes)
+
 
 @dataclass(frozen=True)
 class EpochDiff:
@@ -142,12 +150,17 @@ class EpochRing:
         a capacity change invalidates every row-shaped delta)."""
         self.evicted += len(self._records)
         self._records = []
-        self._latest = _to_np(state)
+        with _trace.span("ring.to_host"):
+            self._latest = _to_np(state)
         self._newest = int(epoch)
 
     def push(self, epoch: int, state) -> None:
-        """Record the transition newest -> ``epoch`` (consecutive publishes)."""
-        f = _to_np(state)
+        """Record the transition newest -> ``epoch`` (consecutive publishes).
+
+        Traced as ``ring.to_host`` (the device->host copy of the patchable
+        fields) then ``ring.delta`` (row compare, XOR record, eviction)."""
+        with _trace.span("ring.to_host"):
+            f = _to_np(state)
         if (self._latest is None
                 or f["vkey"].shape[0] != self._latest["vkey"].shape[0]):
             self.reset(epoch, state)
@@ -155,34 +168,38 @@ class EpochRing:
         if epoch != self._newest + 1:
             raise ValueError(
                 f"non-consecutive publish: {self._newest} -> {epoch}")
-        prev = self._latest
-        scalar_changed = np.zeros(f["vkey"].shape[0], dtype=bool)
-        for name in ("vkey", "valive", "vver", "ecnt"):
-            scalar_changed |= prev[name] != f[name]
-        adj_changed = (prev["adj_packed"] != f["adj_packed"]).any(axis=1)
-        rows = np.nonzero(scalar_changed | adj_changed)[0].astype(np.int32)
-        rec = EpochRecord(
-            epoch=int(epoch),
-            capacity=int(f["vkey"].shape[0]),
-            versions=np.stack([f["ecnt"], f["vver"]], axis=-1),
-            rows=rows,
-            vkey_xor=_xor(prev["vkey"][rows], f["vkey"][rows]),
-            valive_xor=_xor(prev["valive"][rows], f["valive"][rows]),
-            vver_xor=_xor(prev["vver"][rows], f["vver"][rows]),
-            ecnt_xor=_xor(prev["ecnt"][rows], f["ecnt"][rows]),
-            adj_xor=_xor(prev["adj_packed"][rows], f["adj_packed"][rows]),
-        )
-        self._records.append(rec)
-        self._latest = f
-        self._newest = int(epoch)
-        while len(self._records) > self.retain - 1:
-            self._records.pop(0)
-            self.evicted += 1
+        with _trace.span("ring.delta") as sp:
+            prev = self._latest
+            scalar_changed = np.zeros(f["vkey"].shape[0], dtype=bool)
+            for name in ("vkey", "valive", "vver", "ecnt"):
+                scalar_changed |= prev[name] != f[name]
+            adj_changed = (prev["adj_packed"] != f["adj_packed"]).any(axis=1)
+            rows = np.nonzero(scalar_changed | adj_changed)[0].astype(
+                np.int32)
+            rec = EpochRecord(
+                epoch=int(epoch),
+                capacity=int(f["vkey"].shape[0]),
+                versions=np.stack([f["ecnt"], f["vver"]], axis=-1),
+                rows=rows,
+                vkey_xor=_xor(prev["vkey"][rows], f["vkey"][rows]),
+                valive_xor=_xor(prev["valive"][rows], f["valive"][rows]),
+                vver_xor=_xor(prev["vver"][rows], f["vver"][rows]),
+                ecnt_xor=_xor(prev["ecnt"][rows], f["ecnt"][rows]),
+                adj_xor=_xor(prev["adj_packed"][rows],
+                             f["adj_packed"][rows]),
+            )
+            self._records.append(rec)
+            self._latest = f
+            self._newest = int(epoch)
+            while len(self._records) > self.retain - 1:
+                self._records.pop(0)
+                self.evicted += 1
+                if _trace.enabled():
+                    _obs_registry().inc("ring.evictions")
             if _trace.enabled():
-                _obs_registry().inc("ring.evictions")
-        if _trace.enabled():
-            _obs_registry().set("ring.occupancy", len(self._records))
-            _trace.counter("ring.occupancy", len(self._records))
+                sp.set(rows=int(rows.shape[0]), bytes=rec.nbytes)
+                _obs_registry().set("ring.occupancy", len(self._records))
+                _trace.counter("ring.occupancy", len(self._records))
 
     # -- read side ----------------------------------------------------------
     def window(self) -> tuple[int, int]:

@@ -150,6 +150,9 @@ OBS_METRICS: dict[str, tuple[str, str]] = {
     "ingest.round_s": ("histogram", "wall seconds per admission round"),
     "ingest.fused_apply_s": ("histogram",
                              "device wall seconds per fused apply"),
+    "ingest.admit_wait_s": ("histogram",
+                            "seconds from enqueue to the admitting round, "
+                            "per admitted batch"),
     "index.query_s": ("histogram", "wall seconds per index query batch"),
     "index.ring_validate_s": ("histogram",
                               "wall seconds per ring-validated serve"),

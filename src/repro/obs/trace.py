@@ -32,6 +32,14 @@ recorder never maintains an explicit tree — each layer simply opens its
 span around the work, and ``ingest.round`` ends up enclosing
 ``ingest.fused_apply`` which encloses nothing, while ``bfs.session``
 encloses one ``bfs.superstep`` per frontier expansion.
+
+One clock with the device trace: while the recorder is on, every live span
+also enters ``jax.profiler.TraceAnnotation(name)``, so a profiler capture
+shows the program's spans on its host track, on the same clock as the
+device ops. The recorder's own events are stamped from an origin on
+``time.perf_counter_ns``; ``events()`` and the export lead with one
+metadata ("M") event named ``clock_sync`` whose args give that origin, so
+a reader can place the JSON spans on any ``perf_counter`` timeline.
 """
 from __future__ import annotations
 
@@ -63,15 +71,17 @@ _NULL = _NullSpan()
 
 
 class _LiveSpan:
-    """One open interval; appends a complete ("X") event on exit."""
+    """One open interval; appends a complete ("X") event on exit and
+    mirrors itself as a profiler ``TraceAnnotation`` while open."""
 
-    __slots__ = ("_rec", "name", "attrs", "_t0")
+    __slots__ = ("_rec", "name", "attrs", "_t0", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, attrs: dict):
         self._rec = rec
         self.name = name
         self.attrs = attrs
         self._t0 = 0
+        self._ann = None
 
     def set(self, **attrs):
         """Attach/overwrite span attributes mid-flight (e.g. a direction
@@ -80,11 +90,18 @@ class _LiveSpan:
         return self
 
     def __enter__(self):
+        # jax imported lazily, as ``fence`` does: a disabled recorder never
+        # loads it
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(None, None, None)
         self._rec._emit(self.name, self._t0, dur, self.attrs)
         return False
 
@@ -148,9 +165,18 @@ class TraceRecorder:
             self._events = []
 
     # -- export -------------------------------------------------------------
+    def clock_sync(self) -> dict:
+        """The metadata event that ties ``ts`` to ``time.perf_counter_ns``:
+        an event at ``ts`` microseconds started at ``perf_counter_ns``
+        ``args["perf_counter_ns"] + 1000 * ts``."""
+        return {"name": "clock_sync", "ph": "M", "ts": 0.0,
+                "pid": os.getpid(), "tid": 0,
+                "args": {"perf_counter_ns": self._epoch_ns}}
+
     def events(self) -> list[dict]:
+        """The ``clock_sync`` event, then every recorded event in order."""
         with self._lock:
-            return list(self._events)
+            return [self.clock_sync()] + self._events
 
     def export(self) -> dict:
         """Chrome/Perfetto trace-event JSON object (DESIGN.md §14)."""

@@ -170,7 +170,8 @@ class Ticket:
     status: str = "queued"            # queued -> applied | aborted
     results: np.ndarray | None = None
     epoch: int = 0                    # publish epoch the batch landed in
-    wait_s: float = 0.0               # enqueue -> admission
+    # enqueue -> end of the round's fused apply (not -> admission)
+    wait_s: float = 0.0
     retries: int = 0                  # rounds it lost conflict detection
 
     @property
@@ -197,7 +198,8 @@ class IngestStats(StatsView):
         "coalesce_max": ("gauge", 0),          # max batches in one fused call
         "coalesce_lanes_max": ("gauge", 0),    # max fused lanes (pre-padding)
         "retries": ("counter", 0),             # admission round losses
-        "wait_s": ("counter", 0.0),            # total enqueue->admission wait
+        # total wait, enqueue -> end of the round's fused apply
+        "wait_s": ("counter", 0.0),
         "wait_max_s": ("gauge", 0.0),
         "queue_depth_max": ("gauge", 0),
         "queue_depth": ("gauge", 0),           # depth at the last pump
@@ -417,6 +419,13 @@ class IngestPool:
             lanes += t.lanes
             if t.exclusive:
                 break
+        if admitted and _trace.enabled():
+            # the admission wait: enqueue to the round that admits it (a
+            # tracing-only clock read, so untraced runs read it as before)
+            now = self.clock()
+            for t in admitted:
+                _obs_registry().observe("ingest.admit_wait_s",
+                                        now - t.enqueue_t)
         return admitted
 
     def _abort(self, t: Ticket) -> None:
@@ -453,8 +462,11 @@ class IngestPool:
     def pump(self) -> int:
         """One admission round; returns the number of batches applied.
 
-        Traced as one ``ingest.round`` span enclosing ``ingest.admit`` and
-        the round's ``ingest.fused_apply`` (DESIGN.md §14); wall seconds
+        Traced as one ``ingest.round`` span enclosing, in order,
+        ``ingest.admit``, ``ingest.make_batch``, ``ingest.fused_apply``,
+        ``wal.append``, ``ingest.publish`` (itself enclosing
+        ``ring.to_host`` and ``ring.delta``), ``ingest.ack`` and, on the
+        checkpoint cadence, ``ckpt.save`` (DESIGN.md §14); wall seconds
         land in the ``ingest.round_s`` histogram when tracing is on.
         """
         with self._admission, _trace.span("ingest.round") as sp:
@@ -463,6 +475,8 @@ class IngestPool:
                 admitted = self._admit()
             if not admitted:
                 return 0
+            if _trace.enabled():
+                sp.set(batch_ids=[t.batch_id for t in admitted])
             try:
                 applied = self._run_round(admitted)
             finally:
@@ -482,10 +496,11 @@ class IngestPool:
             live = [t for t in admitted if t.status != "aborted"]
             if not live:
                 return 0
-            fused = [op for t in live for op in t.ops]
-            lanes = len(fused)
-            pad = _next_pow2(lanes) if self.pad_lanes else lanes
-            batch = make_op_batch(fused, lanes=pad)
+            with _trace.span("ingest.make_batch"):
+                fused = [op for t in live for op in t.ops]
+                lanes = len(fused)
+                pad = _next_pow2(lanes) if self.pad_lanes else lanes
+                batch = make_op_batch(fused, lanes=pad)
             with _trace.span("ingest.fused_apply", lanes=lanes, pad=pad,
                              batches=len(live)):
                 t0 = time.perf_counter()
@@ -519,7 +534,8 @@ class IngestPool:
                 self.stats.coalesce_max = max(self.stats.coalesce_max, len(live))
                 self.stats.coalesce_lanes_max = max(
                     self.stats.coalesce_lanes_max, lanes)
-                epoch = self._publish(state)
+                with _trace.span("ingest.publish"):
+                    epoch = self._publish(state)
                 if self.wal is not None:
                     self.stats.wal_records = self.wal.stats.records
                     self.stats.wal_bytes = self.wal.stats.bytes
@@ -529,8 +545,9 @@ class IngestPool:
                 # must reproduce it bit-identically (durable-but-unacked)
                 raise SimulatedCrash("post-publish-pre-ack", epoch)
             off = 0
-            with self._mutex:
+            with _trace.span("ingest.ack"), self._mutex:
                 for t in live:
+                    t.epoch = epoch
                     t.results = res[off: off + t.lanes].copy()
                     off += t.lanes
                     t.status = "applied"
@@ -540,8 +557,6 @@ class IngestPool:
                     self.stats.applied += 1
                     self._queue.remove(t)
                 self.stats.queue_depth = len(self._queue)
-            for t in live:
-                t.epoch = epoch
             self._maybe_checkpoint(epoch, state)
             return len(live)
 

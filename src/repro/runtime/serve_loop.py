@@ -77,7 +77,8 @@ class ServeStats(StatsView):
         "ingest_fused_calls": ("gauge", 0),   # coalesced device applies
         "ingest_coalesce_max": ("gauge", 0),  # max batches in one fused call
         "ingest_retries": ("gauge", 0),       # rounds lost to conflicts
-        "ingest_wait_s": ("gauge", 0.0),      # total enqueue->admission wait
+        # total wait, enqueue -> end of the round's fused apply
+        "ingest_wait_s": ("gauge", 0.0),
         "ingest_wait_max_s": ("gauge", 0.0),
         "ingest_queue_depth_max": ("gauge", 0),
         "ingest_epochs": ("gauge", 0),        # snapshot epochs published
@@ -637,13 +638,8 @@ def serve(model, params, prompts: np.ndarray, *, max_new_tokens: int,
              pool.stats.wait_s, pool.stats.epochs)
             if pool is not None else (0, 0, 0, 0.0, 0))
     b, p = prompts.shape
-    _session = _trace.span("serve.session", batch=b,
-                           max_new_tokens=max_new_tokens)
-    _session.__enter__()
-    with _trace.span("serve.prefill", batch=b, prompt_len=p):
-        last, caches = model.prefill(params, {"tokens": jnp.asarray(prompts)})
-        caches = model.cache_from_prefill(caches, cache_len)
-        _trace.fence(last)
+    last, caches = model.prefill(params, {"tokens": jnp.asarray(prompts)})
+    caches = model.cache_from_prefill(caches, cache_len)
     jdecode = jax.jit(model.decode_step)
 
     out = np.zeros((b, max_new_tokens), np.int32)
@@ -718,10 +714,8 @@ def serve(model, params, prompts: np.ndarray, *, max_new_tokens: int,
                     res = graph.get_path(int(q[0]), int(q[1]))
                     stats.getpath_calls += 1
                     stats.getpath_rounds += int(res.rounds)
-        with _trace.span("serve.decode_step", step=i):
-            tok_logits, caches = jdecode(params, caches, tok, jnp.int32(p + i))
-            tok = jnp.argmax(tok_logits, axis=-1).astype(jnp.int32)
-            _trace.fence(tok)
+        tok_logits, caches = jdecode(params, caches, tok, jnp.int32(p + i))
+        tok = jnp.argmax(tok_logits, axis=-1).astype(jnp.int32)
         stats.decode_steps += 1
         stats.decode_tokens += b
     if pool is not None:
@@ -754,8 +748,4 @@ def serve(model, params, prompts: np.ndarray, *, max_new_tokens: int,
         stats.rejected_writes = graph.rejected_writes - rec0[1]
         stats.recoveries = graph.recoveries - rec0[2]
     stats.wall_s = time.time() - t0
-    _session.set(decode_steps=stats.decode_steps,
-                 getpath_calls=stats.getpath_calls,
-                 graph_ops=stats.graph_ops)
-    _session.__exit__(None, None, None)
     return out, stats

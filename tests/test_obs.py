@@ -93,11 +93,13 @@ def test_trace_roundtrip_through_trace_view(tmp_path):
 
     doc = json.loads(pathlib.Path(path).read_text())
     assert set(doc) == {"traceEvents", "displayTimeUnit"}
-    assert len(doc["traceEvents"]) == 4
+    assert len(doc["traceEvents"]) == 5          # clock_sync + 3 spans + 1 C
+    assert doc["traceEvents"][0]["name"] == "clock_sync"
 
     tv = _load_tool("trace_view")
     events = tv.load(path)
     summ = tv.summarize(events)
+    assert "clock_sync" not in summ["spans"]     # metadata is skipped
     assert summ["spans"]["inner"]["count"] == 2
     assert summ["spans"]["outer"]["count"] == 1
     assert summ["spans"]["outer"]["total_us"] > 0
@@ -131,12 +133,12 @@ def test_capture_restores_disabled_state_and_isolates_events():
         assert trace.enabled()
         with trace.span("only"):
             pass
-        assert [e["name"] for e in rec.events()] == ["only"]
+        assert [e["name"] for e in rec.events()] == ["clock_sync", "only"]
     assert not trace.enabled()
     with trace.span("dropped"):   # disabled: the null span records nothing
         pass
-    with trace.capture() as rec2:  # fresh capture starts empty
-        assert rec2.events() == []
+    with trace.capture() as rec2:  # fresh capture holds no span
+        assert [e["ph"] for e in rec2.events()] == ["M"]
 
 
 # -- metrics registry + stat views -----------------------------------------
@@ -344,3 +346,111 @@ def test_get_metrics_shares_pool_registry_with_stats_view():
     srv.submit_client("A", A_OPS)
     srv.pump()
     assert srv.get_metrics()["ingest.applied"] == srv.pool.stats.applied == 1
+
+
+# -- the admission round from inside the program ----------------------------
+
+_ROUND_CHILDREN = ("ingest.admit", "ingest.make_batch", "ingest.fused_apply",
+                   "wal.append", "ingest.publish", "ingest.ack", "ckpt.save")
+
+
+def _inside(inner, outer):
+    return (inner["tid"] == outer["tid"] and outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
+
+
+@pytest.fixture(scope="module")
+def traced_rounds(tmp_path_factory):
+    """Three traced admission rounds of a durable pool (warmed untraced
+    first, so no round compiles): A+B coalesce, then C, then A again."""
+    srv = GraphCoServer(capacity=32, ingest=True,
+                        wal_dir=str(tmp_path_factory.mktemp("wal")))
+    srv.submit_client("W", A_OPS)
+    srv.pump()
+    with trace.capture() as rec:
+        for batch in ((A_OPS, B_OPS, C_OPS), (), (A_OPS,)):
+            for i, ops in enumerate(batch):
+                srv.submit_client(f"c{i}", ops)
+            srv.pump()
+        events = rec.events()
+    return [e for e in events if e["ph"] == "X"]
+
+
+def test_traced_pump_spans_enclose_the_round_work(traced_rounds):
+    rounds = [e for e in traced_rounds if e["name"] == "ingest.round"]
+    assert len(rounds) == 3
+    for r in rounds:
+        kids = [e for e in traced_rounds
+                if e["name"] in _ROUND_CHILDREN and _inside(e, r)]
+        names = [e["name"] for e in sorted(kids, key=lambda e: e["ts"])]
+        assert names == ["ingest.admit", "ingest.make_batch",
+                         "ingest.fused_apply", "wal.append",
+                         "ingest.publish", "ingest.ack"]
+        pub = next(e for e in kids if e["name"] == "ingest.publish")
+        ring = [e for e in traced_rounds
+                if e["name"].startswith("ring.") and _inside(e, pub)]
+        assert [e["name"] for e in sorted(ring, key=lambda e: e["ts"])] == [
+            "ring.to_host", "ring.delta"]
+        assert ring[1]["args"]["rows"] >= 0 and ring[1]["args"]["bytes"] > 0
+        assert r["args"]["admitted"] == len(r["args"]["batch_ids"]) >= 1
+    # B's and C's inserts change rows; the third round's A is a no-op
+    assert [e["args"]["rows"] > 0 for e in traced_rounds
+            if e["name"] == "ring.delta"] == [True, True, False]
+
+
+def test_traced_round_self_time_is_small(traced_rounds):
+    """What no child span names (lock release, stats, WAL record build)
+    stays under a fifth of the round, even on a CPU."""
+    rounds = [e for e in traced_rounds if e["name"] == "ingest.round"]
+    kids = [e for e in traced_rounds if e["name"] in _ROUND_CHILDREN]
+    total = sum(r["dur"] for r in rounds)
+    inner = sum(k["dur"] for r in rounds for k in kids if _inside(k, r))
+    assert 0 <= total - inner < 0.2 * total
+
+
+def test_admit_wait_histogram_counts_each_admitted_batch():
+    """Fake clock: submits at 0, 1, 2; round 1 admits A and B at 3 (waits
+    3, 2) and reads the clock once more after its apply (4); round 2 admits
+    C at 5 (wait 3): three observations summing to 8."""
+    before = dict(GLOBAL.get("ingest.admit_wait_s"))
+    with trace.capture():
+        _scripted_round(_fake_clock())
+    after = GLOBAL.get("ingest.admit_wait_s")
+    assert after["count"] - before["count"] == 3
+    assert after["sum"] - before["sum"] == 8.0
+
+
+def test_export_holds_one_clock_sync_on_perf_counter():
+    t_lo = time.perf_counter_ns()
+    with trace.capture() as rec:
+        with trace.span("timed"):
+            pass
+    t_hi = time.perf_counter_ns()
+    evs = rec.export()["traceEvents"]
+    syncs = [e for e in evs if e["name"] == "clock_sync"]
+    assert len(syncs) == 1 and syncs[0]["ph"] == "M"
+    origin = syncs[0]["args"]["perf_counter_ns"]
+    span = next(e for e in evs if e["name"] == "timed")
+    start = origin + 1e3 * span["ts"]
+    assert t_lo - 1e3 <= start and start + 1e3 * span["dur"] <= t_hi + 1e3
+
+
+def test_untraced_round_reads_the_clock_as_before():
+    """Untraced: the three submits and the two rounds' post-apply reads,
+    exactly (the pinned waits of tests/test_serving_stats depend on it).
+    Traced: one more read per admitting round, for the admission wait."""
+    calls = []
+    fake = _fake_clock()
+
+    def clock():
+        calls.append(1)
+        return fake()
+
+    assert not trace.enabled()
+    _scripted_round(clock)
+    assert len(calls) == 5
+    calls.clear()
+    fake = _fake_clock()
+    with trace.capture():
+        _scripted_round(clock)
+    assert len(calls) == 7
