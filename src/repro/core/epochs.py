@@ -16,15 +16,29 @@ state stays the single O(V^2/32) packed representation; the ring costs
 O(touched_rows * W) per epoch plus one O(V) version vector).
 
 Deltas are XOR patches. For every row whose bytes changed between epoch
-e-1 and e the record stores ``row_index`` plus the XOR of the six field
+e-1 and e the record stores ``row_index`` plus the XOR of the five field
 rows (vkey/valive/vver/ecnt scalars and the packed out-adjacency row).
 XOR is its own inverse, so the SAME record replays the transition in
 either direction: ``state_at(e)`` starts from the newest published state
-and XORs records backward until it lands on e — bit-identical history
-reconstruction, proven by tests/test_epochs.py against the actually
-published states. The in-adjacency is not stored: it is re-derived as the
-packed transpose at reconstruction time (the DESIGN.md §11 transpose
-invariant makes that lossless).
+(the ring's host mirror) and XORs records backward until it lands on e —
+bit-identical history reconstruction, proven by tests/test_epochs.py
+against the actually published states. The in-adjacency is not stored:
+it is re-derived as the packed transpose at reconstruction time (the
+DESIGN.md §11 transpose invariant makes that lossless).
+
+The delta is built on the device. The ring keeps a reference to the
+previous published state's five fields (no copy: the pool's other
+snapshot slot holds the same arrays). A push compares them with the new
+state in one jitted pass (``ring.delta``: a row-wise ``any`` over the
+packed words, the 64 KiB mask at V = 2^16 fetched and its nonzeros
+listed), then gathers the XOR of the changed rows in fixed-size chunks
+and copies only those to the host (``ring.to_host``, which also patches
+the host mirror in place, builds the record and evicts). A row-sharded
+state compares shard by shard under the same jit. Only a ``reset`` (the
+pool's start, a grow barrier) copies the whole state to the host; after
+``load`` the host mirror is uploaded once as the device predecessor, and
+the first push takes the same device path. Counters (under tracing):
+``ring.d2h_bytes`` per push or reset, ``ring.full_copies``.
 
 Three query surfaces ride on the ring (DESIGN.md §13):
 
@@ -43,20 +57,43 @@ retention window produces.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Sequence
+import math
+from dataclasses import dataclass
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.graph import GraphState, pack_transpose
 from repro.obs import trace as _trace
 from repro.obs.metrics import global_registry as _obs_registry
 
-# The six per-row fields a delta record patches, in GraphState order
+# The five per-row fields a delta record patches, in GraphState order
 # (adj_in_packed is derived, never stored; see module docstring).
 _ROW_FIELDS = ("vkey", "valive", "vver", "ecnt", "adj_packed")
+
+# Rows per changed-row gather: one compiled shape per capacity whatever a
+# push's row count (the last chunk is padded and sliced on the host), so
+# no push after the first at a capacity compiles.
+_GATHER_ROWS = 256
+
+
+@jax.jit
+def _changed_rows(prev, new):
+    """bool[V]: rows where any of the five fields differs (the packed words
+    reduced row-wise; row-local, so a row-sharded state compares shard by
+    shard)."""
+    changed = jnp.any(prev[-1] != new[-1], axis=1)
+    for a, b in zip(prev[:-1], new[:-1]):
+        changed = changed | (a != b)
+    return changed
+
+
+@jax.jit
+def _xor_rows(prev, new, idx):
+    """The five fields' XOR at rows ``idx``, in ``_ROW_FIELDS`` order."""
+    return tuple(a[idx] ^ b[idx] for a, b in zip(prev, new))
 
 
 class EpochEvictedError(LookupError):
@@ -89,14 +126,6 @@ class EpochRecord:
     ecnt_xor: np.ndarray      # int32[K]
     adj_xor: np.ndarray       # uint32[K, W] — packed out-adjacency rows
 
-    @property
-    def nbytes(self) -> int:
-        """Host bytes the record holds."""
-        return (self.versions.nbytes + self.rows.nbytes
-                + self.vkey_xor.nbytes + self.valive_xor.nbytes
-                + self.vver_xor.nbytes + self.ecnt_xor.nbytes
-                + self.adj_xor.nbytes)
-
 
 @dataclass(frozen=True)
 class EpochDiff:
@@ -109,19 +138,14 @@ class EpochDiff:
     keys_after: np.ndarray    # int32[K] — vkey at e_to
 
 
-def _to_np(state) -> dict[str, np.ndarray]:
-    """Host copies of the patchable fields (gathers a sharded state)."""
-    return {
-        "vkey": np.asarray(state.vkey),
-        "valive": np.asarray(state.valive),
-        "vver": np.asarray(state.vver),
-        "ecnt": np.asarray(state.ecnt),
-        "adj_packed": np.asarray(state.adj_packed),
-    }
+def _device_fields(state) -> tuple:
+    """The five patchable fields of a (dense or sharded) state, in place."""
+    return tuple(getattr(state, k) for k in _ROW_FIELDS)
 
 
-def _xor(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    return np.bitwise_xor(a, b)
+def _count_d2h(nbytes: int) -> None:
+    if _trace.enabled():
+        _obs_registry().observe("ring.d2h_bytes", nbytes)
 
 
 class EpochRing:
@@ -129,10 +153,11 @@ class EpochRing:
 
     ``retain`` bounds the number of *addressable* epochs (records kept =
     retain - 1 plus the newest full state): after publishing epoch N the
-    window is ``[max(reset_epoch, N - retain + 1), N]``. Push/reads are
-    driven by the ingest pool under its admission mutex; the reconstruction
-    surfaces only touch immutable records, so readers never block writers
-    (DESIGN.md §13).
+    window is ``[max(reset_epoch, N - retain + 1), N]``. A push patches
+    the host mirror in place, so pushes and reads are driven from one
+    thread (the ingest pool's rounds and the server that owns it); the
+    reconstruction surfaces copy the mirror and touch only immutable
+    records, so readers never block writers (DESIGN.md §13).
     """
 
     def __init__(self, retain: int = 64):
@@ -141,65 +166,103 @@ class EpochRing:
         self.retain = int(retain)
         self.evicted = 0              # cumulative records dropped (stats)
         self._records: list[EpochRecord] = []
+        # host mirror of the newest state, patched in place by each push
         self._latest: dict[str, np.ndarray] | None = None
+        # the newest state's five device fields (None after ``load``, until
+        # the first push uploads the mirror)
+        self._prev: tuple | None = None
         self._newest = 0
 
     # -- maintenance (writer side) ------------------------------------------
     def reset(self, epoch: int, state) -> None:
         """Restart retention at ``epoch`` (initial state or a grow barrier:
-        a capacity change invalidates every row-shaped delta)."""
-        self.evicted += len(self._records)
-        self._records = []
+        a capacity change invalidates every row-shaped delta). Traced as
+        ``ring.delta`` (the records dropped) then ``ring.to_host`` (the
+        whole state copied to the host mirror)."""
+        fields = _device_fields(state)
+        nbytes = sum(x.nbytes for x in fields)
+        with _trace.span("ring.delta", rows=int(fields[0].shape[0]),
+                         bytes=nbytes):
+            self.evicted += len(self._records)
+            self._records = []
         with _trace.span("ring.to_host"):
-            self._latest = _to_np(state)
+            # writable host copies (a sharded state is gathered)
+            self._latest = {k: np.array(x) for k, x in zip(_ROW_FIELDS,
+                                                            fields)}
+        if _trace.enabled():
+            _obs_registry().inc("ring.full_copies")
+        _count_d2h(nbytes)
+        self._prev = fields
         self._newest = int(epoch)
 
     def push(self, epoch: int, state) -> None:
         """Record the transition newest -> ``epoch`` (consecutive publishes).
 
-        Traced as ``ring.to_host`` (the device->host copy of the patchable
-        fields) then ``ring.delta`` (row compare, XOR record, eviction)."""
-        with _trace.span("ring.to_host"):
-            f = _to_np(state)
+        Traced as ``ring.delta`` (device compare with the predecessor, mask
+        fetch, row list) then ``ring.to_host`` (gather and copy of the
+        changed rows' XOR, mirror patch, record, eviction). After ``load``
+        the predecessor is the host mirror, uploaded once under
+        ``ring.delta``."""
+        fields = _device_fields(state)
         if (self._latest is None
-                or f["vkey"].shape[0] != self._latest["vkey"].shape[0]):
+                or fields[0].shape[0] != self._latest["vkey"].shape[0]):
             self.reset(epoch, state)
             return
         if epoch != self._newest + 1:
             raise ValueError(
                 f"non-consecutive publish: {self._newest} -> {epoch}")
         with _trace.span("ring.delta") as sp:
-            prev = self._latest
-            scalar_changed = np.zeros(f["vkey"].shape[0], dtype=bool)
-            for name in ("vkey", "valive", "vver", "ecnt"):
-                scalar_changed |= prev[name] != f[name]
-            adj_changed = (prev["adj_packed"] != f["adj_packed"]).any(axis=1)
-            rows = np.nonzero(scalar_changed | adj_changed)[0].astype(
-                np.int32)
-            rec = EpochRecord(
-                epoch=int(epoch),
-                capacity=int(f["vkey"].shape[0]),
-                versions=np.stack([f["ecnt"], f["vver"]], axis=-1),
-                rows=rows,
-                vkey_xor=_xor(prev["vkey"][rows], f["vkey"][rows]),
-                valive_xor=_xor(prev["valive"][rows], f["valive"][rows]),
-                vver_xor=_xor(prev["vver"][rows], f["vver"][rows]),
-                ecnt_xor=_xor(prev["ecnt"][rows], f["ecnt"][rows]),
-                adj_xor=_xor(prev["adj_packed"][rows],
-                             f["adj_packed"][rows]),
-            )
-            self._records.append(rec)
-            self._latest = f
-            self._newest = int(epoch)
-            while len(self._records) > self.retain - 1:
-                self._records.pop(0)
-                self.evicted += 1
-                if _trace.enabled():
-                    _obs_registry().inc("ring.evictions")
+            if self._prev is None:
+                # read only in this push, before the commit patches the
+                # mirror it was uploaded from
+                self._prev = tuple(
+                    jax.device_put(self._latest[k], x.sharding)
+                    for k, x in zip(_ROW_FIELDS, fields))
+            mask = np.asarray(_changed_rows(self._prev, fields))
+            rows = np.flatnonzero(mask).astype(np.int32)
+            chunk = min(_GATHER_ROWS, mask.shape[0])
+            n_chunks = -(-rows.size // chunk)
+            idx = np.zeros(n_chunks * chunk, dtype=np.int32)
+            idx[:rows.size] = rows
+            row_nbytes = sum(x.dtype.itemsize * math.prod(x.shape[1:])
+                             for x in fields)
+            d2h = mask.nbytes + idx.size * row_nbytes
+            sp.set(rows=int(rows.size), bytes=d2h)
+        with _trace.span("ring.to_host"):
+            parts = jax.device_get([
+                _xor_rows(self._prev, fields, idx[i:i + chunk])
+                for i in range(0, idx.size, chunk)])
+            if parts:
+                # slice the padded last chunk first, so that a record holds
+                # its own rows only
+                tail = rows.size - (len(parts) - 1) * chunk
+                xors = [np.concatenate([p[j] for p in parts[:-1]]
+                                       + [parts[-1][j][:tail]])
+                        for j in range(len(_ROW_FIELDS))]
+            else:
+                xors = [self._latest[k][rows] for k in _ROW_FIELDS]
+            self._commit(epoch, rows, xors)
+        _count_d2h(d2h)
+        self._prev = fields
+
+    def _commit(self, epoch: int, rows: np.ndarray, xors: list) -> None:
+        """Patch the host mirror with the XOR rows, append the record of
+        ``epoch`` and evict past ``retain``."""
+        f = self._latest
+        for k, x in zip(_ROW_FIELDS, xors):
+            f[k][rows] ^= x
+        self._records.append(EpochRecord(
+            int(epoch), int(f["vkey"].shape[0]),
+            np.stack([f["ecnt"], f["vver"]], axis=-1), rows, *xors))
+        self._newest = int(epoch)
+        while len(self._records) > self.retain - 1:
+            self._records.pop(0)
+            self.evicted += 1
             if _trace.enabled():
-                sp.set(rows=int(rows.shape[0]), bytes=rec.nbytes)
-                _obs_registry().set("ring.occupancy", len(self._records))
-                _trace.counter("ring.occupancy", len(self._records))
+                _obs_registry().inc("ring.evictions")
+        if _trace.enabled():
+            _obs_registry().set("ring.occupancy", len(self._records))
+            _trace.counter("ring.occupancy", len(self._records))
 
     # -- read side ----------------------------------------------------------
     def window(self) -> tuple[int, int]:
@@ -222,11 +285,11 @@ class EpochRing:
             if rec.epoch <= epoch:
                 break
             r = rec.rows
-            cur["vkey"][r] = _xor(cur["vkey"][r], rec.vkey_xor)
-            cur["valive"][r] = _xor(cur["valive"][r], rec.valive_xor)
-            cur["vver"][r] = _xor(cur["vver"][r], rec.vver_xor)
-            cur["ecnt"][r] = _xor(cur["ecnt"][r], rec.ecnt_xor)
-            cur["adj_packed"][r] = _xor(cur["adj_packed"][r], rec.adj_xor)
+            cur["vkey"][r] ^= rec.vkey_xor
+            cur["valive"][r] ^= rec.valive_xor
+            cur["vver"][r] ^= rec.vver_xor
+            cur["ecnt"][r] ^= rec.ecnt_xor
+            cur["adj_packed"][r] ^= rec.adj_xor
         return cur
 
     def state_at(self, epoch: int) -> GraphState:
@@ -296,7 +359,9 @@ class EpochRing:
         XOR patches).  ``meta`` is JSON-safe and records the layout so
         ``load`` can reassemble records of any count — the reason the
         checkpointer grew ``restore_raw`` (template restores assume a
-        fixed leaf count).
+        fixed leaf count). The mirror's leaves are the arrays the next
+        push patches in place: write them before it (``GraphCheckpointer
+        .save_graph`` blocks until the checkpoint is on disk).
         """
         meta = {"retain": self.retain, "newest": self._newest,
                 "evicted": self.evicted, "n_records": len(self._records),
@@ -313,13 +378,15 @@ class EpochRing:
     @classmethod
     def load(cls, leaves: list[np.ndarray], meta: dict) -> "EpochRing":
         """Rebuild a ring from ``dump`` output, bit-identical: same window,
-        same records, same eviction counter."""
+        same records, same eviction counter. The mirror is copied (a push
+        patches it in place); the first push uploads it as the device
+        predecessor."""
         ring = cls(retain=int(meta["retain"]))
         ring._newest = int(meta["newest"])
         ring.evicted = int(meta["evicted"])
         i = 0
         if meta.get("has_latest"):
-            ring._latest = {k: np.asarray(leaves[i + j])
+            ring._latest = {k: np.array(leaves[i + j])
                             for j, k in enumerate(_ROW_FIELDS)}
             i += len(_ROW_FIELDS)
         cap = (int(ring._latest["vkey"].shape[0])

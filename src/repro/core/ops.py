@@ -121,12 +121,12 @@ def _free_slot(state: GraphState) -> jax.Array:
     return jnp.where(jnp.any(free), idx.astype(jnp.int32), jnp.int32(-1))
 
 
-def _apply_one(state: GraphState, opcode, k1, k2, expect, live=True):
+def _apply_one(state: GraphState, opcode, k1, k2, expect):
     """Apply a single op; returns (state', result).
 
     Branch-free: every op kind's decision is computed from ``state`` and its
-    writes are masked by the opcode (and by ``live``), so the serial lane
-    loop rewrites a few words of the packed matrices in place. Keep it so:
+    writes are masked by the opcode, so the serial lane loop rewrites a
+    few words of the packed matrices in place. Keep it so:
     XLA copies both packed matrices out of every branch of a
     ``lax.switch``/``lax.cond`` that returns the state — O(V^2/32) bytes
     per serial op. The one heavy write, the AddVertex scrub, runs as a
@@ -140,7 +140,6 @@ def _apply_one(state: GraphState, opcode, k1, k2, expect, live=True):
     move; edge ops are the masked single-bit RMW on both mirrors plus the
     paper's FAA on the source row's ``ecnt``.
     """
-    opcode = jnp.where(live, opcode, OP_NOP)
     s1 = find_slot(state, k1)
     s2 = find_slot(state, k2)
     is_addv = opcode == OP_ADD_V
@@ -215,16 +214,20 @@ def _serial_masked(state: GraphState, ops: OpBatch, mask: jax.Array,
 
     Unselected lanes keep their ``res0`` entry. This is both the reference
     engine (mask = all lanes) and the fast engine's correction pass
-    (mask = conflicting lanes).
+    (mask = conflicting lanes). The loop visits the selected lanes only,
+    so its trip count is theirs, not the batch's.
     """
+    lanes = jnp.nonzero(mask, size=ops.lanes, fill_value=0)[0]
 
-    def body(i, carry):
+    def body(j, carry):
         st, res = carry
+        i = lanes[j]
         st, r = _apply_one(st, ops.opcode[i], ops.key1[i], ops.key2[i],
-                           ops.expect[i], live=mask[i])
-        return st, res.at[i].set(jnp.where(mask[i], r, res[i]))
+                           ops.expect[i])
+        return st, res.at[i].set(r)
 
-    return jax.lax.fori_loop(0, ops.lanes, body, (state, res0))
+    n = jnp.sum(mask.astype(jnp.int32))
+    return jax.lax.fori_loop(0, n, body, (state, res0))
 
 
 @jax.jit

@@ -162,6 +162,11 @@ OBS_METRICS: dict[str, tuple[str, str]] = {
     "ring.evictions": ("counter", "delta records dropped by retention"),
     "ring.resolve_depth": ("histogram",
                            "XOR records replayed per state_at()"),
+    "ring.d2h_bytes": ("histogram",
+                       "bytes copied device->host per ring push or reset"),
+    "ring.full_copies": ("counter",
+                         "ring resets that copied the whole state to the "
+                         "host"),
     "wal.append_s": ("histogram",
                      "wall seconds per durable WAL append (incl. fsync)"),
     "ckpt.save_s": ("histogram",

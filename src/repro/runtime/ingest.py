@@ -465,7 +465,7 @@ class IngestPool:
         Traced as one ``ingest.round`` span enclosing, in order,
         ``ingest.admit``, ``ingest.make_batch``, ``ingest.fused_apply``,
         ``wal.append``, ``ingest.publish`` (itself enclosing
-        ``ring.to_host`` and ``ring.delta``), ``ingest.ack`` and, on the
+        ``ring.delta`` and ``ring.to_host``), ``ingest.ack`` and, on the
         checkpoint cadence, ``ckpt.save`` (DESIGN.md §14); wall seconds
         land in the ``ingest.round_s`` histogram when tracing is on.
         """
@@ -635,7 +635,7 @@ class IngestPool:
             # load the PREVIOUS published step
             self.ckpt.save_torn(**kwargs)
             raise SimulatedCrash("ckpt-mid-write", epoch)
-        self.ckpt.save_graph(blocking=True, **kwargs)
+        self.ckpt.save_graph(**kwargs)
         self._rounds_since_ckpt = 0
         with self._mutex:
             self.stats.ckpt_saves += 1

@@ -94,17 +94,18 @@ class GraphCheckpointer:
         return leaves + ring_leaves, extra
 
     def save_graph(self, *, epoch, state, ring, linearization, epoch_log,
-                   next_batch_id, index_stamp=None, blocking=True) -> None:
-        """One durable graph snapshot, published atomically at step=epoch."""
+                   next_batch_id, index_stamp=None) -> None:
+        """One durable graph snapshot, published atomically at step=epoch.
+        Returns once it is on disk: the ring's leaves are its live host
+        mirror, which the next push patches in place."""
         leaves, extra = self._leaves_manifest(
             epoch=epoch, state=state, ring=ring, linearization=linearization,
             epoch_log=epoch_log, next_batch_id=next_batch_id,
             index_stamp=index_stamp)
         with _trace.span("ckpt.save", epoch=int(epoch), leaves=len(leaves)):
             t0 = time.perf_counter()
-            self.inner.save(int(epoch), leaves, extra=extra,
-                            blocking=blocking)
-            if blocking and _trace.enabled():
+            self.inner.save(int(epoch), leaves, extra=extra, blocking=True)
+            if _trace.enabled():
                 _obs_registry().observe("ckpt.save_s",
                                         time.perf_counter() - t0)
 
@@ -197,14 +198,16 @@ def recover(ckpt: GraphCheckpointer | str | None, wal: WriteAheadLog | str | Non
             out.index_stamp = extra.get("index_stamp")
             out.ring = ring
             out.ckpt_step = int(extra["epoch"])
-        if dense is None:
+        fresh = dense is None
+        if fresh:
             dense = make_graph(capacity)
             out.epoch_log = {0: 0}
-            out.ring = EpochRing(retain_epochs)
-            out.ring.reset(0, dense)
 
         state = partition.shard_state(mesh, dense) if mesh is not None \
             else dense
+        if fresh:
+            # the ring's device predecessor is the state replay starts from
+            out.ring.reset(0, state)
 
         # 2) idempotent WAL replay of every epoch past the checkpoint
         if wal is not None:

@@ -16,14 +16,20 @@ import pytest
 from repro.core import (
     OP_ADD_E,
     OP_ADD_V,
+    OP_CON_V,
     OP_REM_E,
     OP_REM_V,
     EpochEvictedError,
     EpochRing,
+    epochs,
     make_graph,
+    packed_width,
+    partition,
     version_vector,
 )
 from repro.core.distributed import make_graph_mesh
+from repro.obs import trace
+from repro.obs.metrics import GLOBAL
 from repro.runtime.ingest import IngestPool
 
 FIELDS = ("vkey", "valive", "vver", "ecnt", "adj_packed", "adj_in_packed")
@@ -199,8 +205,6 @@ def test_sharded_pool_ring_reconstructs_dense_bit_identical():
     """A sharded pool's ring records host gathers; reconstruction is the
     dense form of every published epoch (time-travel is read-only, so the
     gathered dense answer is the contract)."""
-    from repro.core import partition
-
     mesh = make_graph_mesh()
     state = partition.shard_state(mesh, make_graph(32))
     pool = IngestPool(state, mesh=mesh, retain_epochs=64)
@@ -212,3 +216,168 @@ def test_sharded_pool_ring_reconstructs_dense_bit_identical():
     for e in range(lo, hi + 1):
         _assert_states_equal(pool.state_at(e), published[e],
                              f"epoch {e} (sharded pool):")
+
+
+# -- the delta built on the device -----------------------------------------
+RING_FIELDS = ("vkey", "valive", "vver", "ecnt", "adj_packed")
+EDGES_INTO_0 = [(OP_ADD_V, k) for k in range(6)] + [
+    (OP_ADD_E, k, 0) for k in (1, 2, 3)] + [(OP_ADD_E, 4, 5)]
+
+
+def _oracle_record(before, after):
+    """The record of ``before`` -> ``after`` by plain numpy: full host
+    copies of both published states, row compare, XOR."""
+    b = [np.asarray(getattr(before, k)) for k in RING_FIELDS]
+    a = [np.asarray(getattr(after, k)) for k in RING_FIELDS]
+    changed = np.zeros(a[0].shape[0], dtype=bool)
+    for x, y in zip(b, a):
+        changed |= (x != y).reshape(x.shape[0], -1).any(axis=1)
+    rows = np.flatnonzero(changed).astype(np.int32)
+    versions = np.stack([a[3], a[2]], axis=-1)
+    return rows, [x[rows] ^ y[rows] for x, y in zip(b, a)], versions
+
+
+def _slots(state, keys):
+    vkey = np.asarray(state.vkey)
+    return sorted(int(np.flatnonzero(vkey == k)[0]) for k in keys)
+
+
+def _bit_flips(state, cells):
+    """``state`` then one state per (row, word) cell, each with that packed
+    word's low bit flipped in the previous state."""
+    out = [state]
+    for r, w in cells:
+        adj = out[-1].adj_packed
+        out.append(out[-1]._replace(adj_packed=adj.at[r, w].set(
+            adj[r, w] ^ 1)))
+    return out
+
+
+# each case: capacity, client batches (one publish each), the batch index
+# before which the ring is dumped and reloaded, and what its records show;
+# or hand-built states pushed to a bare ring
+DELTA_CASES = {
+    # RemV 0 clears column 0: the rows of 1, 2, 3 change, no lane names them
+    "remv_column_clear": dict(
+        batches=[EDGES_INTO_0, [(OP_REM_V, 0)]],
+        expect=lambda recs, pub: recs[-1].rows.tolist() == _slots(
+            pub[1], (0, 1, 2, 3))),
+    "add_vertex": dict(
+        batches=[[(OP_ADD_V, 7)], [(OP_ADD_V, 8), (OP_ADD_V, 9)]],
+        expect=lambda recs, pub: [r.rows.size for r in recs] == [1, 2]),
+    "add_edge": dict(
+        batches=[EDGES_INTO_0, [(OP_ADD_E, 5, 4)]],
+        expect=lambda recs, pub: recs[-1].rows.tolist() == _slots(
+            pub[2], (5,))),
+    "remove_edge": dict(
+        batches=[EDGES_INTO_0, [(OP_REM_E, 4, 5)]],
+        expect=lambda recs, pub: recs[-1].rows.tolist() == _slots(
+            pub[2], (4,))),
+    "no_row_changes": dict(
+        batches=[EDGES_INTO_0, [(OP_CON_V, 1)], [(OP_REM_E, 1, 2)]],
+        expect=lambda recs, pub: [r.rows.size for r in recs[1:]] == [0, 0]),
+    # more changed rows than one gather chunk holds
+    "rows_span_chunks": dict(
+        capacity=512,
+        batches=[[(OP_ADD_V, k) for k in range(300)], [(OP_REM_V, 3)]],
+        expect=lambda recs, pub: recs[0].rows.size == 300
+        > epochs._GATHER_ROWS),
+    # capacity 4 and six keys force a grow: the ring resets at the grown
+    # epoch and the pushes after it compare against the grown state
+    "grow_barrier": dict(
+        capacity=4,
+        batches=[[(OP_ADD_V, 10 * k)] for k in range(6)]
+        + [[(OP_ADD_E, 0, 10)], [(OP_REM_V, 0)]],
+        expect=lambda recs, pub: recs[0].capacity > 4
+        and recs[-1].epoch == 8),
+    # the ring reloaded from its dump has no device predecessor: its first
+    # push uploads the host mirror and compares on the device
+    "first_push_after_load": dict(
+        batches=[EDGES_INTO_0, [(OP_ADD_E, 5, 1)], [(OP_REM_V, 1)],
+                 [(OP_ADD_V, 9)]],
+        reload_at=2,
+        expect=lambda recs, pub: [r.epoch for r in recs] == [1, 2, 3, 4]),
+    # a row whose packed words change while its counters do not (no op
+    # does that today; the compare must not lean on ecnt/vver)
+    "adjacency_bits_only": dict(
+        states=lambda: _bit_flips(make_graph(32), [(3, 0), (3, 0), (9, 0)]),
+        expect=lambda recs, pub: [r.rows.tolist() for r in recs]
+        == [[3], [3], [9]]),
+    "sharded_pool": dict(
+        sharded=True,
+        batches=[EDGES_INTO_0, [(OP_REM_V, 0)], [(OP_ADD_E, 4, 1)]],
+        expect=lambda recs, pub: recs[1].rows.size == 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DELTA_CASES))
+def test_device_delta_records_match_a_host_oracle(case):
+    """Every retained record, and the host mirror, equal byte for byte
+    what a plain numpy diff of the published states gives."""
+    spec = DELTA_CASES[case]
+    if "states" in spec:
+        published = dict(enumerate(spec["states"]()))
+        ring = EpochRing(retain=64)
+        ring.reset(0, published[0])
+        for e in range(1, len(published)):
+            ring.push(e, published[e])
+    else:
+        state = make_graph(spec.get("capacity", 32))
+        mesh = None
+        if spec.get("sharded"):
+            mesh = make_graph_mesh()
+            state = partition.shard_state(mesh, state)
+        pool = IngestPool(state, mesh=mesh, retain_epochs=64)
+        published = {0: pool.snapshot()}
+        for i, batch in enumerate(spec["batches"]):
+            if i == spec.get("reload_at"):
+                pool.ring = EpochRing.load(*pool.ring.dump())
+            pool.submit("c", batch)
+            pool.flush()
+            published[pool.epoch] = pool.snapshot()
+        ring = pool.ring
+    recs = list(ring._records)
+    assert recs and spec["expect"](recs, published), [
+        r.rows.tolist() for r in recs]
+    for rec in recs:
+        rows, xors, versions = _oracle_record(published[rec.epoch - 1],
+                                              published[rec.epoch])
+        got = [rec.rows, rec.vkey_xor, rec.valive_xor, rec.vver_xor,
+               rec.ecnt_xor, rec.adj_xor, rec.versions]
+        for g, w in zip(got, [rows, *xors, versions]):
+            assert (g.dtype, g.shape) == (w.dtype, w.shape), rec.epoch
+            assert g.tobytes() == w.tobytes(), rec.epoch
+    newest = published[max(published)]
+    for k in RING_FIELDS:
+        assert ring._latest[k].tobytes() == np.asarray(
+            getattr(newest, k)).tobytes(), k
+
+
+def test_device_delta_compiles_once_and_counts_what_crosses():
+    """Pushes of 300, 0, 1 and 2 changed rows at one capacity: no compile
+    after the first push, no whole-state copy after the pool's reset, and
+    each push copies the mask plus its padded row chunks."""
+    cap, chunk = 512, epochs._GATHER_ROWS
+    row_bytes = 4 + 1 + 4 + 4 + 4 * packed_width(cap)
+    with trace.capture():
+        full0 = GLOBAL.get("ring.full_copies")
+        pool = IngestPool(make_graph(cap), retain_epochs=8)
+        assert GLOBAL.get("ring.full_copies") == full0 + 1
+        pool.submit("c", [(OP_ADD_V, 1)])
+        pool.flush()
+        compiled = (epochs._changed_rows._cache_size(),
+                    epochs._xor_rows._cache_size())
+        for batch, k in (([(OP_ADD_V, j) for j in range(2, 302)], 300),
+                         ([(OP_CON_V, 1)], 0), ([(OP_ADD_E, 1, 2)], 1),
+                         ([(OP_REM_V, 2)], 2)):
+            before = dict(GLOBAL.get("ring.d2h_bytes"))
+            pool.submit("c", batch)
+            pool.flush()
+            assert pool.ring._records[-1].rows.size == k
+            after = GLOBAL.get("ring.d2h_bytes")
+            assert after["count"] == before["count"] + 1
+            assert after["sum"] - before["sum"] == (
+                cap + -(-k // chunk) * chunk * row_bytes)
+        assert (epochs._changed_rows._cache_size(),
+                epochs._xor_rows._cache_size()) == compiled
+        assert GLOBAL.get("ring.full_copies") == full0 + 1

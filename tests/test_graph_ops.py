@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from repro.core import (
-    OP_ADD_E, OP_ADD_V, OP_CON_E, OP_CON_V, OP_REM_E, OP_REM_V,
+    OP_ADD_E, OP_ADD_V, OP_CON_E, OP_CON_V, OP_NOP, OP_REM_E, OP_REM_V,
     R_CAS_FAIL, R_EDGE_ADDED, R_EDGE_NOT_PRESENT, R_EDGE_PRESENT,
     R_EDGE_REMOVED, R_FALSE, R_TABLE_FULL, R_TRUE, R_VERTEX_NOT_PRESENT,
     add_edge, add_vertex, apply_ops, apply_ops_fast, compact, contains_edge,
@@ -135,3 +135,27 @@ def test_engines_match_on_conflicting_batch():
     for f in g1._fields:
         np.testing.assert_array_equal(np.asarray(getattr(g1, f)),
                                       np.asarray(getattr(g2, f)))
+
+
+def test_correction_pass_applies_only_the_selected_lanes():
+    """The serial pass visits the masked lanes in lane order and leaves the
+    others' results as given: the same as the reference engine on a batch
+    whose unselected lanes are NOPs."""
+    from repro.core.ops import _serial_masked
+
+    g = build([1, 2, 3], [(1, 2), (3, 1)])
+    lanes = [(OP_REM_V, 9), (OP_REM_V, 1), (OP_ADD_V, 7), (OP_ADD_E, 3, 2),
+             (OP_ADD_V, 8), (OP_REM_E, 3, 1)]
+    mask = [False, True, False, True, False, True]
+    res0 = jnp.full((len(lanes),), -7, jnp.int32)
+    got, res = _serial_masked(g, make_op_batch(lanes), jnp.asarray(mask),
+                              res0)
+    want, res_want = apply_ops(g, make_op_batch(
+        [op if m else (OP_NOP,) for op, m in zip(lanes, mask)]))
+    res, res_want = np.asarray(res), np.asarray(res_want)
+    np.testing.assert_array_equal(res[~np.asarray(mask)], -7)
+    np.testing.assert_array_equal(res[np.asarray(mask)],
+                                  res_want[np.asarray(mask)])
+    for f in g._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)),
+                                      np.asarray(getattr(want, f)))
